@@ -784,23 +784,21 @@ def csv_rows_from(payload: Dict[str, Any]) -> List[Tuple[str, float, str]]:
 
 # ---------------------------------------------------------------------------
 # forced-device re-exec (mirrors kernels_bench --sharded)
-_REEXEC_SENTINEL = "REPRO_DIP_FLEET_REEXEC"
+def _held_to_cpu_without_forced_devices() -> bool:
+    """Whether ``JAX_PLATFORMS=cpu`` holds this process to the CPU and no
+    host device count is forced yet.  Decided from the environment alone,
+    before JAX is imported: a process on an accelerator never starts a CPU
+    child."""
+    return (os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+            and "--xla_force_host_platform_device_count"
+            not in os.environ.get("XLA_FLAGS", ""))
 
 
 def _reexec_with_devices(argv: Sequence[str], devices: int) -> int:
-    import jax
-
-    if os.environ.get(_REEXEC_SENTINEL):
-        raise SystemExit(
-            f"fleet: re-exec with forced host devices still sees "
-            f"{jax.device_count()} device(s) (< {devices}); check "
-            "JAX_PLATFORMS/XLA_FLAGS overrides")
     env = dict(os.environ)
-    env[_REEXEC_SENTINEL] = "1"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices} "
         + env.get("XLA_FLAGS", "")).strip()
-    env["JAX_PLATFORMS"] = "cpu"
     root = pathlib.Path(__file__).resolve().parent.parent
     env["PYTHONPATH"] = os.pathsep.join(
         [str(root / "src")]
@@ -811,12 +809,8 @@ def _reexec_with_devices(argv: Sequence[str], devices: int) -> int:
     return proc.returncode
 
 
-def _needs_reexec(cells: Sequence[Tuple[str, str, str]]) -> bool:
-    if not any(s != "gspmd" for _, _, s in cells):
-        return False
-    import jax
-
-    return jax.device_count() < 2
+def _has_sharded(cells: Sequence[Tuple[str, str, str]]) -> bool:
+    return any(s != "gspmd" for _, _, s in cells)
 
 
 # ---------------------------------------------------------------------------
@@ -865,9 +859,17 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     args = ap.parse_args(argv)
 
     cells, matrix = _select_cells(args)
-    if _needs_reexec(cells):
-        return _reexec_with_devices(
-            list(argv) if argv is not None else sys.argv[1:], args.devices)
+    if _has_sharded(cells):
+        if _held_to_cpu_without_forced_devices():
+            return _reexec_with_devices(
+                list(argv) if argv is not None else sys.argv[1:], args.devices)
+        import jax
+
+        if jax.device_count() < 2:
+            raise SystemExit(
+                f"fleet: sharded cells need >= 2 devices; "
+                f"{jax.default_backend()} has {jax.device_count()} (on a CPU "
+                "host, JAX_PLATFORMS=cpu forces host devices)")
 
     print(f"== fleet: {len(cells)} cells ({matrix} matrix) ==")
     payload = run_matrix(cells, matrix=matrix, iters=args.iters)
@@ -900,8 +902,10 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 def run(csv_rows) -> None:
     """benchmarks.run harness contract: tiny matrix, validated, diffed
     against the committed baseline when present, rows appended."""
+    import jax
+
     cells = tiny_cells([a for a in _all_archs()])
-    if _needs_reexec(cells):
+    if _has_sharded(cells) and jax.device_count() < 2:
         # Under the single-process harness we cannot re-exec just this
         # module; drop the sharded cells and say so rather than fail.
         print("fleet: <2 devices and no re-exec under benchmarks.run; "

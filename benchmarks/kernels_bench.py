@@ -483,28 +483,26 @@ def compare_sharded(
     }
 
 
-_REEXEC_SENTINEL = "REPRO_DIP_SHARDED_REEXEC"
+def _held_to_cpu_without_forced_devices() -> bool:
+    """Whether ``JAX_PLATFORMS=cpu`` holds this process to the CPU and no
+    host device count is forced yet.  Decided from the environment alone,
+    before JAX picks a backend: a process on an accelerator never starts a
+    CPU child."""
+    return (os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+            and "--xla_force_host_platform_device_count"
+            not in os.environ.get("XLA_FLAGS", ""))
 
 
 def _reexec_with_devices(argv: Sequence[str], devices: int) -> int:
-    """`--sharded` needs a multi-device topology; XLA locks the device count
-    at first init, so spawn a fresh interpreter with forced host devices.
-    One level deep only: if the child STILL comes up short (e.g. a platform
-    override the forced-count flag cannot affect), it errors instead of
-    re-execing again."""
-    if os.environ.get(_REEXEC_SENTINEL):
-        raise SystemExit(
-            f"--sharded: re-exec with forced host devices still sees "
-            f"{jax.device_count()} device(s) (< {devices}); check "
-            "JAX_PLATFORMS/XLA_FLAGS overrides"
-        )
+    """`--sharded` on the CPU needs a multi-device topology; XLA locks the
+    device count at first init, so spawn a fresh interpreter with forced
+    host devices.  The child sees the forced count in XLA_FLAGS and never
+    re-execs again."""
     env = dict(os.environ)
-    env[_REEXEC_SENTINEL] = "1"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices} "
         + env.get("XLA_FLAGS", "")
     ).strip()
-    env["JAX_PLATFORMS"] = "cpu"  # the forced count only exists on cpu
     root = pathlib.Path(__file__).resolve().parent.parent
     env["PYTHONPATH"] = os.pathsep.join(
         [str(root / "src")] + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else [])
@@ -784,9 +782,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     help="run ONLY the fused-vs-unfused epilogue comparison")
     ap.add_argument("--sharded", action="store_true",
                     help="run ONLY the explicit-sharding comparison (dip_tp/"
-                         "dip_fsdp vs GSPMD-xla); re-execs itself with "
-                         "--xla_force_host_platform_device_count when the "
-                         "topology is single-device")
+                         "dip_fsdp vs GSPMD-xla); under JAX_PLATFORMS=cpu it "
+                         "re-execs itself with "
+                         "--xla_force_host_platform_device_count")
     ap.add_argument("--devices", type=int, default=8,
                     help="virtual device count for --sharded (default 8)")
     ap.add_argument("--upstream", action="store_true",
@@ -804,12 +802,18 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
     csv_rows: List = []
     if args.sharded:
-        if jax.device_count() < args.devices:
+        if _held_to_cpu_without_forced_devices():
             return _reexec_with_devices(
                 ["--sharded", "--devices", str(args.devices),
                  "--iters", str(args.iters), "--out", args.out]
                 + (["--tiny"] if args.tiny else []),
                 args.devices,
+            )
+        if jax.device_count() < args.devices:
+            raise SystemExit(
+                f"--sharded needs {args.devices} devices; "
+                f"{jax.default_backend()} has {jax.device_count()} (on a "
+                "CPU host, JAX_PLATFORMS=cpu forces host devices)"
             )
         m, k, n = (8, 256, 256) if args.tiny else (64, 512, 512)
         print(f"== explicit sharding vs GSPMD-xla "

@@ -28,7 +28,7 @@ def main():
                     help="DiP storage + Pallas fused kernel for every matmul")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch).reduced(compute_dtype="float32")
+    cfg = get_config(args.arch).reduced()
     if args.dip:
         cfg = dataclasses.replace(cfg, matmul_backend="pallas_dip")
     print(f"serving reduced {cfg.name} ({cfg.param_count()/1e6:.1f}M params, "
@@ -50,7 +50,7 @@ def main():
         print(f"  request {rid}: {len(results[rid]):>3} new tokens  {results[rid][:10]}")
     s = server.last_stats
     print(f"done: {s['decode_steps']} decode steps, {s['tok_per_s']:.1f} tok/s "
-          f"(CPU host; interpret-mode kernels when --dip)")
+          f"on {jax.default_backend()}")
 
 
 if __name__ == "__main__":

@@ -176,10 +176,7 @@ def _device_tag() -> str:
     (block-size winners do not transfer across device generations)."""
     import jax  # deferred: keep module import free of backend initialization
 
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:  # uninitializable backend — still want a usable path
-        kind = jax.default_backend()
+    kind = jax.devices()[0].device_kind
     tag = "".join(c if c.isalnum() else "-" for c in kind.lower()).strip("-")
     return tag or "unknown"
 

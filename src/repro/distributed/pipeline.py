@@ -122,7 +122,6 @@ def pipeline_apply(
         mesh=mesh,
         in_specs=(jax.tree_util.tree_map(lambda _: pspec, stage_params), rep),
         out_specs=rep,
-        check_rep=False,
     )
     return fn(stage_params, x)
 

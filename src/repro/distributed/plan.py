@@ -58,6 +58,7 @@ __all__ = [
     "LAYER_RULES",
     "ShardingPlan",
     "make_plan",
+    "make_mesh",
     "make_production_mesh",
     "make_local_mesh",
     "STRATEGIES",
@@ -69,6 +70,16 @@ STRATEGIES = ("gspmd", "tp", "fsdp", "sp", "ep", "pp")
 
 # --------------------------------------------------------------------------
 # mesh construction
+def make_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: the plans place arrays with
+    sharding constraints and hand-written ``shard_map`` bodies, and leave
+    the rest to GSPMD propagation rather than to sharding-in-types."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """Target topology: one v5e pod slice of 256 chips (16x16), or two pods.
 
@@ -79,7 +90,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1, stage: int = 1) -> Mesh:
@@ -89,8 +100,8 @@ def make_local_mesh(data: int = 1, model: int = 1, stage: int = 1) -> Mesh:
     ``distributed.pipeline``); the 2-axis shape is kept when absent so
     existing checkpoint manifests round-trip unchanged."""
     if stage > 1:
-        return jax.make_mesh((stage, data, model), ("stage", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((stage, data, model), ("stage", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 # --------------------------------------------------------------------------

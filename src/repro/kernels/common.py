@@ -7,30 +7,35 @@ rolls combined with column-mask selects — a classic barrel shifter.  Static
 rolls and selects are cheap Mosaic ops, so the de-shear costs
 O(log2(tile) * tile * bn) vector work per weight block, amortized against
 O(bm * tile * bn) MXU work.
+
+Mosaic rotates 32-bit vectors only, so narrower blocks (bf16, int8, fp8)
+are widened to 32 bits for the shear and narrowed back afterwards — exact,
+since every narrow value is representable in the wide type — and the MXU
+still receives operands of the storage width.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["VMEM", "SMEM", "CompilerParams", "shard_map", "deshear_block",
-           "shear_block", "rotate_left_dynamic"]
+__all__ = ["shard_map", "deshear_block", "shear_block", "rotate_left_dynamic"]
 
-# jax renamed these between releases (MemorySpace.VMEM <-> VMEM,
-# CompilerParams <-> TPUCompilerParams); resolve whichever spelling exists so
-# the kernels compile against any toolchain the container bakes in.
-VMEM = getattr(pltpu, "VMEM", None) or pltpu.MemorySpace.VMEM
-SMEM = getattr(pltpu, "SMEM", None) or pltpu.MemorySpace.SMEM
-CompilerParams = getattr(pltpu, "TPUCompilerParams", None) or pltpu.CompilerParams
+# Every sharded module takes shard_map from here.  The kernels' bodies are
+# per-shard Pallas calls whose replication JAX cannot infer, so the
+# varying-manual-axes check is off at every call site.
+shard_map = functools.partial(jax.shard_map, check_vma=False)
 
-# shard_map graduated from jax.experimental to the top level; every sharded
-# module imports THIS alias so the repo tracks the move in one place.
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:  # pre-graduation toolchains
-    from jax.experimental.shard_map import shard_map  # noqa: F811
+
+def _wide_dtype(dtype) -> jnp.dtype:
+    """The 32-bit type a block is rotated in (its own type if already 32-bit)."""
+    dtype = jnp.dtype(dtype)
+    if dtype.itemsize == 4:
+        return dtype
+    return jnp.dtype(jnp.int32 if jnp.issubdtype(dtype, jnp.integer) else jnp.float32)
 
 
 def _barrel_shear(block: jax.Array, tile: int, *, inverse: bool) -> jax.Array:
@@ -40,11 +45,14 @@ def _barrel_shear(block: jax.Array, tile: int, *, inverse: bool) -> jax.Array:
     Forward (``inverse=False``):  out[j, i] = in[(j + i%tile) % tile, i]
     Inverse (``inverse=True``):   out[j, i] = in[(j - i%tile) % tile, i]
 
-    Implemented as log2(tile) static rolls + masked selects per 64-row group.
+    Implemented as log2(tile) static rolls + masked selects per 64-row group,
+    in 32 bits (module doc); the result has the input's dtype.
     """
     bk, bn = block.shape
     if bk % tile or bn % tile:
         raise ValueError(f"block {block.shape} not a multiple of permutation tile {tile}")
+    out_dtype = block.dtype
+    block = block.astype(_wide_dtype(out_dtype))
     col = jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1) % tile
     groups = []
     for g in range(bk // tile):
@@ -58,7 +66,8 @@ def _barrel_shear(block: jax.Array, tile: int, *, inverse: bool) -> jax.Array:
             w = jnp.where((col & bit) != 0, rolled, w)
             bit *= 2
         groups.append(w)
-    return groups[0] if len(groups) == 1 else jnp.concatenate(groups, axis=0)
+    out = groups[0] if len(groups) == 1 else jnp.concatenate(groups, axis=0)
+    return out.astype(out_dtype)
 
 
 def deshear_block(p_block: jax.Array, tile: int = 64) -> jax.Array:

@@ -51,6 +51,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import common
 from repro.kernels import epilogue as epi
@@ -165,9 +166,9 @@ def dip_matmul_pallas(
     extra_specs = epi.operand_block_specs(
         epilogue, block_m=block_m, block_n=block_n, block_k=block_k
     )
-    scratch = [common.VMEM((block_m, block_n), acc_dtype)]
+    scratch = [pltpu.VMEM((block_m, block_n), acc_dtype)]
     if spec.dual_weight:
-        scratch.append(common.VMEM((block_m, block_n), acc_dtype))
+        scratch.append(pltpu.VMEM((block_m, block_n), acc_dtype))
 
     return pl.pallas_call(
         functools.partial(
@@ -184,7 +185,7 @@ def dip_matmul_pallas(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=scratch,
-        compiler_params=common.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -36,6 +36,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import common
 from repro.kernels import epilogue as epi
@@ -48,15 +49,12 @@ __all__ = ["dip_matmul_q_pallas", "fp8_compute_dtype", "fp8_native_supported"]
 def fp8_native_supported() -> bool:
     """Whether this device has a native reduced-precision MXU path for fp8
     operands (TPU v5+ / GPU).  CPU interpret mode always emulates."""
-    try:
-        backend = jax.default_backend()
-        if backend == "gpu":
-            return True
-        if backend == "tpu":
-            kind = jax.devices()[0].device_kind.lower()
-            return any(tag in kind for tag in ("v5", "v6", "v7"))
-    except Exception:
-        pass
+    backend = jax.default_backend()
+    if backend == "gpu":
+        return True
+    if backend == "tpu":
+        kind = jax.devices()[0].device_kind.lower()
+        return any(tag in kind for tag in ("v5", "v6", "v7"))
     return False
 
 
@@ -195,9 +193,9 @@ def dip_matmul_q_pallas(
         with_scales=True,
     )
 
-    scratch = [common.VMEM((block_m, block_n), acc_dtype)]
+    scratch = [pltpu.VMEM((block_m, block_n), acc_dtype)]
     if spec.dual_weight:
-        scratch.append(common.VMEM((block_m, block_n), acc_dtype))
+        scratch.append(pltpu.VMEM((block_m, block_n), acc_dtype))
 
     return pl.pallas_call(
         functools.partial(
@@ -214,7 +212,7 @@ def dip_matmul_q_pallas(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=scratch,
-        compiler_params=common.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

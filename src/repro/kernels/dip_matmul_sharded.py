@@ -325,7 +325,6 @@ def dip_tp_matmul(
                 tuple(eop_specs),
             ),
             out_specs=P(None, ax),
-            check_rep=False,
         )(x2, datas, scales, pops, eops)
         return out2[:m2, : w0.d_out].reshape(lead + (w0.d_out,))
 
@@ -370,9 +369,10 @@ def dip_tp_matmul(
             partials[0].dtype, jnp.floating
         ):
             return jax.lax.psum(partials[0], ax)  # exact int32 reduction
-        # ONE psum equation, even for the dual-weight swiglu pair
+        # ONE psum equation, even for the dual-weight swiglu pair: the pair
+        # is stacked, since a tuple binds one psum per leaf
         zs = jax.lax.psum(
-            tuple(p.astype(jnp.float32) for p in partials), ax
+            jnp.stack([p.astype(jnp.float32) for p in partials]), ax
         )
         if epilogue == "none":
             return zs[0].astype(xl.dtype if floating else partials[0].dtype)
@@ -392,7 +392,6 @@ def dip_tp_matmul(
             tuple(P(None, None) for _ in eops),
         ),
         out_specs=P(None, None),
-        check_rep=False,
     )(x2, datas, scales, eops)
     return out2[:m2, : w0.d_out].reshape(lead + (w0.d_out,))
 
@@ -504,7 +503,6 @@ def dip_fsdp_matmul(
             tuple(eop_specs),
         ),
         out_specs=P(ax, None),
-        check_rep=False,
     )(x2p, datas, scales, pops, eops)
     return out2[:m2, : w0.d_out].reshape(lead + (w0.d_out,))
 
@@ -646,7 +644,6 @@ def dip_sp_matmul(
                 tuple(eop_specs),
             ),
             out_specs=P(None, ax),
-            check_rep=False,
         )(x2p, datas, scales, pops, eops)
         return out2[:m2, : w0.d_out].reshape(lead + (w0.d_out,))
 
@@ -723,6 +720,5 @@ def dip_sp_matmul(
             tuple(eop_specs),
         ),
         out_specs=P(ax, None),
-        check_rep=False,
     )(x2p, datas, scales, eops)
     return out2[:m2, : w0.d_out].reshape(lead + (w0.d_out,))

@@ -30,6 +30,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import common
 from repro.kernels import epilogue as epi
@@ -135,9 +136,9 @@ def dip_systolic_pallas(
         epilogue, block_m=block_m, block_n=array_n, block_k=array_n
     )
 
-    scratch = [common.VMEM((block_m, array_n), acc_dtype)]
+    scratch = [pltpu.VMEM((block_m, array_n), acc_dtype)]
     if spec.dual_weight:
-        scratch.append(common.VMEM((block_m, array_n), acc_dtype))
+        scratch.append(pltpu.VMEM((block_m, array_n), acc_dtype))
 
     return pl.pallas_call(
         functools.partial(
@@ -153,7 +154,7 @@ def dip_systolic_pallas(
         out_specs=pl.BlockSpec((block_m, array_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=scratch,
-        compiler_params=common.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

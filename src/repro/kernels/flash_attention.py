@@ -16,7 +16,8 @@ Causal masking via absolute positions: ``q_offset`` (per batch*head row,
 anywhere in the key sequence, which is exactly the serving chunked-prefill
 shape: Sq new tokens attending a cache of ``q_offset`` earlier keys.
 ``kv_len`` bounds the live keys per row (cache capacity / Sk padding).
-Both ride as scalar-per-row SMEM inputs.  KV blocks entirely above the
+Both ride as scalar-prefetch operands: (BH,) int32 arrays in SMEM that the
+kernel indexes with its batch*head grid index.  KV blocks entirely above the
 causal diagonal or past ``kv_len`` are skipped (no MXU work, no VMEM
 traffic for masked tiles — the block-diagonal savings that make causal
 flash ~2x the throughput of the masked-dense form).
@@ -34,8 +35,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels import common
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention_pallas"]
 
@@ -53,8 +53,9 @@ def _kernel(qo_ref, kvl_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    qo = qo_ref[0, 0]
-    kvl = kvl_ref[0, 0]
+    b = pl.program_id(0)
+    qo = qo_ref[b]
+    kvl = kvl_ref[b]
     kv_start = kv_idx * block_k
 
     # block skipping: a KV block entirely above the causal diagonal (its
@@ -102,13 +103,13 @@ def _kernel(qo_ref, kvl_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
 
 def _per_row_i32(val, bh: int, default: int) -> jax.Array:
-    """Broadcast a None / scalar / (BH,) value to the (BH, 1) SMEM layout."""
+    """Broadcast a None / scalar / (BH,) value to the (BH,) SMEM layout."""
     if val is None:
         val = default
     arr = jnp.asarray(val, jnp.int32)
     if arr.ndim == 0:
         arr = jnp.broadcast_to(arr, (bh,))
-    return arr.reshape(bh, 1)
+    return arr.reshape(bh)
 
 
 @functools.partial(
@@ -153,24 +154,23 @@ def flash_attention_pallas(
         functools.partial(
             _kernel, scale=scale, block_q=bq, block_k=bk, causal=causal
         ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, i, j: (b, 0),
-                         memory_space=common.SMEM),
-            pl.BlockSpec((1, 1), lambda b, i, j: (b, 0),
-                         memory_space=common.SMEM),
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, dv), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, bq, d), lambda b, i, j, *_: (b, i, 0)),
+                pl.BlockSpec((1, bk, d), lambda b, i, j, *_: (b, j, 0)),
+                pl.BlockSpec((1, bk, dv), lambda b, i, j, *_: (b, j, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, bq, dv), lambda b, i, j, *_: (b, i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, dv), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((bh, sqp, dv), q.dtype),
-        scratch_shapes=[
-            common.VMEM((bq, 1), jnp.float32),
-            common.VMEM((bq, 1), jnp.float32),
-            common.VMEM((bq, dv), jnp.float32),
-        ],
-        compiler_params=common.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -41,8 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-from repro.kernels import common
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "DEFAULT_BLOCK_T",
@@ -125,6 +124,10 @@ def lm_head_ce_pallas(
     if vocab > vp:
         raise ValueError(f"vocab_size {vocab} exceeds head width {vp}")
 
+    # the MXU runs at the activations' width: a wider head (an f32 master
+    # weight under bf16 compute) would promote every tile to f32 and its
+    # double-buffered (d, block_v) blocks overflow the kernel's VMEM
+    w = w.astype(x.dtype)
     bt = max(8, min(block_t, -(-t // 8) * 8))
     bv = max(128, min(block_v, -(-vp // 128) * 128))
     tp = -(-t // bt) * bt
@@ -153,11 +156,11 @@ def lm_head_ce_pallas(
             jax.ShapeDtypeStruct((tp, 1), jnp.float32),
         ],
         scratch_shapes=[
-            common.VMEM((bt, 1), jnp.float32),
-            common.VMEM((bt, 1), jnp.float32),
-            common.VMEM((bt, 1), jnp.float32),
+            pltpu.VMEM((bt, 1), jnp.float32),
+            pltpu.VMEM((bt, 1), jnp.float32),
+            pltpu.VMEM((bt, 1), jnp.float32),
         ],
-        compiler_params=common.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,7 +1,11 @@
 """Serving driver: batched requests through the serving engine.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch llama3-8b --reduced \
+    PYTHONPATH=src python -m repro.launch.serve --arch yi-9b \
         --requests 8 --max-new 32
+
+``--reduced`` (the default) serves the config's tiny same-family variant;
+``--no-reduced`` serves its published widths.  Every projection computes
+in the config's ``compute_dtype``.
 
 ``--engine paged`` (default) serves through the continuous-batching engine
 with the paged KV cache (``repro.serving``); ``--engine wave`` runs the
@@ -12,11 +16,13 @@ K/V at int8 (~2x sequences per byte; see docs/serving.md).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 
 import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tf_model
 from repro.runtime import Server, ServerConfig, WaveServer
 from repro.runtime.server import Request
@@ -25,7 +31,10 @@ from repro.runtime.server import Request
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the tiny same-family variant (default); "
+                         "--no-reduced serves the published widths")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=32)
@@ -55,24 +64,20 @@ def main():
                          "projections before serving (tiled backends only)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     if args.dip:
-        import dataclasses
-        cfg = dataclasses.replace(cfg, matmul_backend="pallas_dip",
-                                  compute_dtype="float32")
+        cfg = dataclasses.replace(cfg, matmul_backend="pallas_dip")
     if args.quantize:
-        import dataclasses
         from repro.api import quant
         cfg = dataclasses.replace(
             cfg, quantization=args.quantize,
             matmul_backend=quant.scheme_info(args.quantize).backend,
-            compute_dtype="float32",
         )
     plan = None
     if args.sharded:
-        import dataclasses
         from repro.distributed.plan import make_local_mesh, make_plan
         # explicit multi-chip serving: TP over all local devices, or FSDP
         # over all local devices, dispatched per the weights' plan metadata
@@ -81,8 +86,7 @@ def main():
                 else make_local_mesh(data=n_dev, model=1))
         backend = {"tp": "dip_tp", "fsdp": "dip_fsdp"}[args.sharded]
         cfg = dataclasses.replace(cfg, sharding=args.sharded,
-                                  matmul_backend=backend,
-                                  compute_dtype="float32")
+                                  matmul_backend=backend)
         plan = make_plan(mesh, cfg, "decode")
     if args.autotune:
         # registers measured tuning entries before the first forward traces,

@@ -18,6 +18,7 @@ from repro.configs import get_config
 from repro.distributed.plan import (
     STRATEGIES, make_local_mesh, make_plan, make_production_mesh,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import AdamW, cosine_schedule
 from repro.runtime import Trainer, TrainerConfig
 
@@ -53,6 +54,7 @@ def main():
                          "projections before training (tiled backends only)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -336,9 +336,14 @@ def _moe_ffn_ep(
         if shared_out is not None:
             out = out + shared_out
         # ONE psum for the stats pair: aux averages over devices (each saw
-        # 1/T of the tokens), drops sum
-        aux_sum, dropped = jax.lax.psum((r["aux"], r["dropped"]), ax)
-        return out, aux_sum / t, dropped
+        # 1/T of the tokens), drops sum.  The pair is stacked in f32 (a
+        # tuple binds one psum per leaf); drop counts stay exact below 2**24
+        stats = jax.lax.psum(
+            jnp.stack([r["aux"].astype(jnp.float32),
+                       r["dropped"].astype(jnp.float32)]), ax
+        )
+        dropped = jnp.round(stats[1]).astype(r["dropped"].dtype)
+        return out, stats[0] / t, dropped
 
     xspec = P(ax, None, None) if batch_split else P(None, ax, None)
     out, aux, dropped = shard_map(
@@ -351,7 +356,6 @@ def _moe_ffn_ep(
             tuple(P(None, None) for _ in s_scales),
         ),
         out_specs=(xspec, P(), P()),
-        check_rep=False,
     )(x, p["router"], banks, s_datas, s_scales)
     constrain = layers.resolve_constrain(plan, constrain)
     return constrain(out, "act_btd"), aux, dropped

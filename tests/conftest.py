@@ -18,19 +18,27 @@ os.environ.setdefault("REPRO_DIP_NO_TUNING_CACHE", "1")
 
 import numpy as np
 import pytest
+from hypothesis import settings
+
+# The first example of a property test pays for JAX tracing and compiling,
+# which no per-example deadline can budget for.
+settings.register_profile("repro", deadline=None)
+settings.load_profile("repro")
 
 _FORCED_PREAMBLE = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n}"
 import jax, jax.numpy as jnp, numpy as np
+from repro.distributed.plan import make_mesh
 """
 
 
 def run_forced_devices(body: str, devices: int = 4, timeout: int = 600) -> str:
     """Run ``body`` in a fresh interpreter with ``devices`` forced host CPU
     devices (XLA locks the device count at first init, so multi-device code
-    can never run in the pytest process itself).  ``jax``/``jnp``/``np`` are
-    pre-imported; asserts on the child's exit code and returns its stdout."""
+    can never run in the pytest process itself).  ``jax``/``jnp``/``np`` and
+    ``make_mesh`` (Auto-axes meshes) are pre-imported; asserts on the
+    child's exit code and returns its stdout."""
     code = _FORCED_PREAMBLE.format(n=devices) + body
     proc = subprocess.run(
         [sys.executable, "-c", code],

@@ -3,8 +3,7 @@
 Every registered matmul backend x supported operand dtype must
 
   * match its pure-jnp oracle in ``kernels/ref.py`` on *aligned and ragged*
-    shapes (property-generated through ``tests/_hypothesis_shim`` — real
-    hypothesis when installed, the deterministic fallback otherwise), within
+    shapes (property-generated with hypothesis), within
     the per-dtype tolerances documented in ``docs/quantization.md``;
   * honour autodiff where the backend is differentiable (activation grads
     everywhere, weight grads on the float backends — quantized storage is a
@@ -20,7 +19,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from _hypothesis_shim import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro import api
 from repro.kernels import ref

@@ -2,14 +2,15 @@
 
 import numpy as np
 import jax.numpy as jnp
-from _hypothesis_shim import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import dataflow, permute
 
 dims = st.integers(min_value=1, max_value=24)
 
 
-@settings(max_examples=40, deadline=None)
+@settings(max_examples=40)
 @given(rows=dims, cols=dims, seed=st.integers(0, 2**31 - 1))
 def test_permute_matches_paper_pseudocode(rows, cols, seed):
     w = np.random.default_rng(seed).integers(-100, 100, size=(rows, cols))
@@ -18,7 +19,7 @@ def test_permute_matches_paper_pseudocode(rows, cols, seed):
     np.testing.assert_array_equal(got, want)
 
 
-@settings(max_examples=40, deadline=None)
+@settings(max_examples=40)
 @given(rows=dims, cols=dims, seed=st.integers(0, 2**31 - 1))
 def test_permute_roundtrip(rows, cols, seed):
     w = np.random.default_rng(seed).normal(size=(rows, cols)).astype(np.float32)
@@ -27,7 +28,7 @@ def test_permute_roundtrip(rows, cols, seed):
     np.testing.assert_allclose(np.asarray(back), w)
 
 
-@settings(max_examples=25, deadline=None)
+@settings(max_examples=25)
 @given(
     rows=st.integers(1, 150),
     cols=st.integers(1, 150),
@@ -42,7 +43,7 @@ def test_tiled_permute_roundtrip_any_shape(rows, cols, tile, seed):
     np.testing.assert_allclose(np.asarray(back), w)
 
 
-@settings(max_examples=25, deadline=None)
+@settings(max_examples=25)
 @given(n=st.integers(2, 16), m=st.integers(1, 32), seed=st.integers(0, 2**31 - 1))
 def test_rolled_mac_identity(n, m, seed):
     """out[m,i] = sum_r x[m,(i+r)%N] * P[r,i]  ==  x @ W  (paper Sec III-B)."""

@@ -1,12 +1,13 @@
 """Cycle-accurate simulators: numerically exact + timing == eqs. (1)-(7)."""
 
 import numpy as np
-from _hypothesis_shim import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import analytical, permute, simulator
 
 
-@settings(max_examples=20, deadline=None)
+@settings(max_examples=20)
 @given(
     n=st.integers(2, 12),
     extra=st.integers(0, 12),
@@ -24,7 +25,7 @@ def test_dip_simulator_exact_and_on_time(n, extra, s, seed):
     assert res.tfpu == analytical.dip_tfpu(n)
 
 
-@settings(max_examples=20, deadline=None)
+@settings(max_examples=20)
 @given(
     n=st.integers(2, 10),
     extra=st.integers(0, 10),

@@ -1,6 +1,6 @@
 """The fleet driver's schema IS the acceptance contract (benchmarks/fleet.py).
 
-Property-tested (hypothesis shim): synthetic fleet documents round-trip
+Property-tested (hypothesis): synthetic fleet documents round-trip
 through ``validate_fleet_json`` and JSON serialization; the baseline differ
 rejects launch-count regressions, collective-count regressions, vanished
 cells, and newly-failing stages no matter where in the matrix they occur.
@@ -15,7 +15,8 @@ import json
 import numpy as np
 import pytest
 
-from _hypothesis_shim import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 fleet = pytest.importorskip("benchmarks.fleet")
 

@@ -3,7 +3,8 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_shim import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.configs.base import ArchConfig
 from repro.models import moe, transformer as tf_model
@@ -121,7 +122,7 @@ def test_shared_experts_added():
     )
 
 
-@settings(max_examples=10, deadline=None)
+@settings(max_examples=10)
 @given(tokens=st.integers(4, 64), e=st.sampled_from([4, 8]), k=st.sampled_from([1, 2]))
 def test_capacity_function_bounds(tokens, e, k):
     cfg = _cfg(e=e, k=k)

@@ -23,7 +23,7 @@ def test_pipeline_parallel_equals_sequential():
     out = _run("""
 from repro.distributed.pipeline import pipeline_apply
 from repro.kernels.dip_matmul_sharded import collective_schedule, count_collectives
-mesh = jax.make_mesh((4,), ("stage",))
+mesh = make_mesh((4,), ("stage",))
 n_stages, n_micro, mb, d = 4, 8, 2, 16
 key = jax.random.PRNGKey(0)
 params = {"w": jax.random.normal(key, (n_stages, d, d)) * 0.3,
@@ -93,10 +93,10 @@ print("PP_TRAIN_OK")
 def test_compressed_psum_shard_map():
     out = _run("""
 from functools import partial
-from jax.experimental.shard_map import shard_map
+from repro.kernels.common import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.distributed.compression import compressed_psum
-mesh = jax.make_mesh((4,), ("data",))
+mesh = make_mesh((4,), ("data",))
 x = jnp.arange(16, dtype=jnp.float32).reshape(4, 4) / 7.0
 f = shard_map(lambda v: compressed_psum(v, "data"), mesh=mesh,
               in_specs=P("data", None), out_specs=P("data", None))
@@ -131,7 +131,7 @@ ref_step = jax.jit(tf_model.train_step_fn(cfg, opt))
 sref, mref = ref_step(state, batch)
 
 # sharded on a (2, 2) data x model mesh, threaded as a first-class plan
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 plan = make_plan(mesh, cfg, "train")
 pshard = plan.param_shardings(tf_model.param_template(cfg))
 with mesh:
@@ -155,7 +155,7 @@ def test_plan_pspecs_valid_for_all_archs():
 from repro.configs import ALL_ARCHS, get_config
 from repro.distributed.plan import make_plan
 from repro.models.transformer import param_template
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 for arch in ALL_ARCHS:
     cfg = get_config(arch)
     for mode in ("train", "decode"):
@@ -180,7 +180,7 @@ from repro.distributed.plan import make_plan
 # d_ff=70 does not divide the 4-wide model axis -> w_gate/w_up fall back
 cfg = ArchConfig(name="t", family="dense", n_layers=2, d_model=64, n_heads=4,
                  n_kv_heads=4, d_ff=70, vocab_size=256, head_dim=16)
-mesh = jax.make_mesh((1, 4), ("data", "model"))
+mesh = make_mesh((1, 4), ("data", "model"))
 plan = make_plan(mesh, cfg, "train")
 with warnings.catch_warnings(record=True) as caught:
     warnings.simplefilter("always")

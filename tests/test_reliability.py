@@ -26,7 +26,8 @@ import os
 import numpy as np
 import pytest
 
-from _hypothesis_shim import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 import jax
 import jax.numpy as jnp

@@ -4,7 +4,7 @@ Four load-bearing properties:
 
 1. **Allocator invariants** — the free-list block allocator never hands out
    the null block, never double-allocates, is all-or-nothing, and raises on
-   double-free (property-tested via the hypothesis shim).
+   double-free (property-tested with hypothesis).
 2. **Row independence** — a greedy request's output is bit-identical whether
    it runs alone or packed with arbitrary batch-mates, across every model
    family (GQA, MLA, pure-SSM, hybrid).  This is THE correctness property of
@@ -20,7 +20,8 @@ Four load-bearing properties:
 import numpy as np
 import pytest
 
-from _hypothesis_shim import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 from conftest import run_forced_devices as _run
 
 import jax
@@ -352,8 +353,7 @@ ref = Engine(cfg, params, engine_cfg=EngineConfig(slots=2, max_seq=32,
 ref.add_request(prompt, sp, rid=0)
 want = ref.run()[0]
 
-tp_cfg = dataclasses.replace(cfg, sharding="tp", matmul_backend="dip_tp",
-                             compute_dtype="float32")
+tp_cfg = dataclasses.replace(cfg, sharding="tp", matmul_backend="dip_tp")
 mesh = make_local_mesh(data=1, model=2)
 plan = make_plan(mesh, tp_cfg, "decode")
 eng = Engine(tp_cfg, params, engine_cfg=EngineConfig(slots=2, max_seq=32,

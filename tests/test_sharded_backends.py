@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import pytest
 
 from conftest import run_forced_devices as _run
+from repro.distributed.plan import make_mesh
 
 from repro import api
 from repro.checkpoint import restore_pytree, save_pytree
@@ -359,7 +360,7 @@ toks = jax.random.randint(key, (2, 8), 0, 512)
 ref_cfg = dataclasses.replace(cfg, matmul_backend="xla", sharding="gspmd")
 ref_logits, _, _ = tf_model.forward(params, ref_cfg, tokens=toks)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 plan = make_plan(mesh, cfg, "train")
 params_tp = plan.attach_params(params)
 # every 2-D projection in this config divides the mesh: all plans explicit
@@ -455,7 +456,7 @@ def test_weight_plan_survives_checkpoint_and_validates_on_restore(tmp_path):
         restore_pytree(path, bad)
 
     # a live mesh that lost the saved plan's axis is detected
-    mesh1 = jax.make_mesh((1,), ("stage",))
+    mesh1 = make_mesh((1,), ("stage",))
     lost = WeightPlan("column", axis="stage", fsdp=None, mesh=mesh1)
     # rewrite the manifest as if saved from a {model}-axis mesh restoring
     # onto a {stage}-only mesh: axis names must survive re-mesh
