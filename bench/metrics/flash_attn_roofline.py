@@ -1,0 +1,24 @@
+"""Share of its roofline, in percent, that the flash-attention prefill
+kernel reaches over the traced slice.  One kernel event is one layer of one
+prefill chunk.  The chunks are those of the engine steps that ran inside
+the traced slice, each a chunk's rows of causal attention at the prompt
+position it started at (bench/work.py); their mean least time a layer,
+times the kernel's events, over the kernel's summed time."""
+
+from bench import work
+
+
+def read(rec):
+    tr = rec.get("trace")
+    k = tr and tr["kernels"].get("flash_attention")
+    if not k or not k["seconds"]:
+        return None
+    cell = rec["cell"]
+    peak = work.peaks(cell.device.device_kind)
+    c = cell.mix["engine"]["prefill_chunk"]
+    lo, hi = cell.trace_window
+    times = [work.least_time(*work.attention_work(cell.dims, c, t.chunk_offset), peak)
+             for t in rec["ticks"] if t.prefill and lo <= t.start and t.end <= hi]
+    if not times:
+        return None
+    return 100.0 * k["count"] * (sum(times) / len(times)) / k["seconds"]
