@@ -19,6 +19,11 @@ rows are independent: a greedy request's output is bit-identical whether it
 runs alone or packed with arbitrary batch-mates — the property
 ``tests/test_serving.py`` pins down.
 
+Each tick leaves host spans in ``serving.telemetry`` (``engine.step`` and,
+under it, admit / prefill / import / decode / fetch / sample), so the time
+the host spends drawing tokens and blocked on the device can be read apart
+(docs/serving.md).
+
 Under memory pressure (``ensure`` fails mid-decode) the scheduler's LIFO
 victim is evicted: blocks freed, request re-queued at the front carrying its
 generated tokens (re-prefilled on re-admission).
@@ -47,12 +52,19 @@ import numpy as np
 from repro import api
 from repro.models import transformer as tf_model
 from repro.serving import kv_cache as kvc
-from repro.serving import sampling
+from repro.serving import sampling, telemetry
 from repro.serving.scheduler import (
     DONE, PREFILL, QUEUED, RUNNING, FCFSScheduler, SamplingParams, ServeRequest,
 )
 
 __all__ = ["Engine", "EngineConfig"]
+
+
+def _named(fn, name: str):
+    """``fn`` renamed, so that its jitted program reads ``jit_<name>`` in a
+    device trace."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 @dataclasses.dataclass
@@ -118,15 +130,16 @@ class Engine:
             plan=plan,
         )
 
-        self._decode = jax.jit(tf_model.paged_decode_step_fn(cfg, plan=plan))
+        self._decode = jax.jit(_named(
+            tf_model.paged_decode_step_fn(cfg, plan=plan), "engine_decode"))
         # chunked prefill routes through the fused flash-attention kernel
         # (api.attention backend "flash") whenever the logits stay local: the
         # kernel takes the chunk's cache offset as a *traced* q_offset, so
         # every chunk of every prompt shares one compiled shape.  Sharded
         # plans keep the GSPMD online-softmax path (the kernel is per-shard).
-        self._prefill_fwd = jax.jit(tf_model.decode_step_fn(
+        self._prefill_fwd = jax.jit(_named(tf_model.decode_step_fn(
             cfg, plan=plan, attn_backend="flash" if plan is None else None,
-        ))
+        ), "engine_prefill_chunk"))
         self._import = jax.jit(kvc.make_import_fn(
             cfg, num_blocks, self.block_size, self.kv_quant
         ))
@@ -237,6 +250,8 @@ class Engine:
         self.request_stats[req.rid] = {
             "prompt_len": int(req.prompt.size),
             "new_tokens": len(req.generated),
+            "queue_s": (req.admit_s - req.arrival_s
+                        if req.admit_s is not None else None),
             "ttft_s": (req.first_token_s - req.arrival_s
                        if req.first_token_s is not None else None),
             "latency_s": req.finish_s - req.arrival_s,
@@ -275,21 +290,24 @@ class Engine:
         """One vectorized draw over the (B, V) logits; rows without a request
         fall back to greedy and are ignored by the caller."""
         b, v = logits.shape
-        temp = np.zeros(b, np.float32)
-        top_k = np.zeros(b, np.int64)
-        top_p = np.ones(b, np.float32)
-        uniforms = np.zeros((b, v), np.float64)
-        for i, r in enumerate(reqs):
-            if r is None:
-                continue
-            sp = r.sampling
-            temp[i], top_k[i], top_p[i] = sp.temperature, sp.top_k, sp.top_p
-            if sp.temperature > 0:
-                uniforms[i] = r.rng.random(v)
-        return sampling.sample_tokens(
-            logits, temperature=temp, top_k=top_k, top_p=top_p,
-            uniforms=uniforms,
-        )
+        live = [r for r in reqs if r is not None]
+        rid = live[0].rid if b == 1 and live else -1
+        with telemetry.span("engine.sample", rid=rid, n=len(live)):
+            temp = np.zeros(b, np.float32)
+            top_k = np.zeros(b, np.int64)
+            top_p = np.ones(b, np.float32)
+            uniforms = np.zeros((b, v), np.float64)
+            for i, r in enumerate(reqs):
+                if r is None:
+                    continue
+                sp = r.sampling
+                temp[i], top_k[i], top_p[i] = sp.temperature, sp.top_k, sp.top_p
+                if sp.temperature > 0:
+                    uniforms[i] = r.rng.random(v)
+            return sampling.sample_tokens(
+                logits, temperature=temp, top_k=top_k, top_p=top_p,
+                uniforms=uniforms,
+            )
 
     # ------------------------------------------------------------- faults --
     def _handle_fault(self, req: ServeRequest) -> None:
@@ -321,9 +339,9 @@ class Engine:
         the bottom rung of the degradation ladder.  Built on first fault."""
         if self._decode_xla is None:
             cfg_xla = dataclasses.replace(self.cfg, matmul_backend="xla")
-            self._decode_xla = jax.jit(
-                tf_model.paged_decode_step_fn(cfg_xla, plan=self.plan)
-            )
+            self._decode_xla = jax.jit(_named(
+                tf_model.paged_decode_step_fn(cfg_xla, plan=self.plan),
+                "engine_decode_xla"))
         return self._decode_xla
 
     def _expire(self, req: ServeRequest) -> None:
@@ -357,18 +375,21 @@ class Engine:
         if self._paged and not self.kv.can_allocate(plen):
             return
         req = self.scheduler.pop(self._tick)
-        req.state = PREFILL
-        req.slot = slot
-        self._slots[slot] = req
-        if self._paged:
-            ok = self.kv.ensure(slot, plen)   # can_allocate held above
-            assert ok, "allocator disagreed with can_allocate"
-        buf = np.zeros(self._prefill_buf_len, np.int32)
-        buf[:plen] = req.serve_prompt
-        self._prefilling = req
-        self._prefill_tokens = buf
-        self._prefill_done = 0
-        self._prefill_cache = tf_model.init_cache(self.cfg, 1, self._prefill_buf_len)
+        if req.admit_s is None:
+            req.admit_s = time.monotonic()
+        with telemetry.span("engine.admit", rid=req.rid, n=plen):
+            req.state = PREFILL
+            req.slot = slot
+            self._slots[slot] = req
+            if self._paged:
+                ok = self.kv.ensure(slot, plen)   # can_allocate held above
+                assert ok, "allocator disagreed with can_allocate"
+            buf = np.zeros(self._prefill_buf_len, np.int32)
+            buf[:plen] = req.serve_prompt
+            self._prefilling = req
+            self._prefill_tokens = buf
+            self._prefill_done = 0
+            self._prefill_cache = tf_model.init_cache(self.cfg, 1, self._prefill_buf_len)
 
     # ------------------------------------------------------------ prefill --
     def _advance_prefill(self) -> None:
@@ -380,34 +401,37 @@ class Engine:
         done = self._prefill_done
         last_logits = None
 
-        if self.cfg.ssm_state:
-            # The recurrent state is exact only over the real tokens, so the
-            # tail that doesn't fill a chunk runs token-by-token through the
-            # O(1) decode path (<= chunk-1 cheap steps) instead of padding.
-            if plen - done >= c:
+        with telemetry.span("engine.prefill", rid=req.rid, n=min(c, plen - done)):
+            if self.cfg.ssm_state:
+                # The recurrent state is exact only over the real tokens, so
+                # the tail that doesn't fill a chunk runs token-by-token
+                # through the O(1) decode path (<= chunk-1 cheap steps)
+                # instead of padding.
+                if plen - done >= c:
+                    chunk = self._prefill_tokens[done:done + c][None]
+                    last_logits, self._prefill_cache = self._prefill_fwd(
+                        self.params, self._prefill_cache, jnp.asarray(chunk)
+                    )
+                    done += c
+                    self._prefill_chunks += 1
+                else:
+                    while done < plen:
+                        tok = self._prefill_tokens[done:done + 1][None]
+                        last_logits, self._prefill_cache = self._prefill_fwd(
+                            self.params, self._prefill_cache, jnp.asarray(tok)
+                        )
+                        done += 1
+                    self._prefill_chunks += 1
+            else:
+                # attention-only: the padded tail of the final chunk writes
+                # cache rows >= plen, which the import drops and positions
+                # never reach
                 chunk = self._prefill_tokens[done:done + c][None]
                 last_logits, self._prefill_cache = self._prefill_fwd(
                     self.params, self._prefill_cache, jnp.asarray(chunk)
                 )
                 done += c
                 self._prefill_chunks += 1
-            else:
-                while done < plen:
-                    tok = self._prefill_tokens[done:done + 1][None]
-                    last_logits, self._prefill_cache = self._prefill_fwd(
-                        self.params, self._prefill_cache, jnp.asarray(tok)
-                    )
-                    done += 1
-                self._prefill_chunks += 1
-        else:
-            # attention-only: the padded tail of the final chunk writes cache
-            # rows >= plen, which the import drops and positions never reach
-            chunk = self._prefill_tokens[done:done + c][None]
-            last_logits, self._prefill_cache = self._prefill_fwd(
-                self.params, self._prefill_cache, jnp.asarray(chunk)
-            )
-            done += c
-            self._prefill_chunks += 1
         self._prefill_done = done
 
         if done >= plen:
@@ -415,16 +439,19 @@ class Engine:
 
     def _finish_prefill(self, req: ServeRequest, plen: int, last_logits) -> None:
         slot = req.slot
-        pools = self.kv.pools["layers"]
-        self.kv.pools["layers"] = self._import(
-            pools, self._prefill_cache["layers"],
-            jnp.int32(slot), jnp.int32(plen),
-            jnp.asarray(self.kv.table_row(slot)),
-        )
+        with telemetry.span("engine.import", rid=req.rid, n=plen):
+            pools = self.kv.pools["layers"]
+            self.kv.pools["layers"] = self._import(
+                pools, self._prefill_cache["layers"],
+                jnp.int32(slot), jnp.int32(plen),
+                jnp.asarray(self.kv.table_row(slot)),
+            )
         # first token: logits row of the prompt's last position within the
         # final prefill call (padded chunk: plen-1 relative to chunk start;
         # SSM single-token tail: the only row)
-        row = np.asarray(last_logits[0, (plen - 1) - (self._prefill_done - last_logits.shape[1])])
+        with telemetry.span("engine.fetch", rid=req.rid, n=1):
+            row = np.asarray(
+                last_logits[0, (plen - 1) - (self._prefill_done - last_logits.shape[1])])
         if self.ecfg.verify and not np.isfinite(row).all():
             self._handle_fault(req)
             return
@@ -439,42 +466,47 @@ class Engine:
 
     # ------------------------------------------------------------- decode --
     def _decode_once(self) -> None:
-        # grow every running slot's table for the position it writes next;
-        # under exhaustion the LIFO victim is evicted until the rest fit
-        for req in sorted(self._running, key=lambda r: r.admit_index):
-            if req.state != RUNNING:
-                continue
-            while not self._ensure(req.slot, int(self._ctx[req.slot]) + 1):
-                victims = self._running
-                victim = self.scheduler.pick_victim(victims)
-                if victim is req and len(victims) == 1:
-                    raise RuntimeError(
-                        f"KV pool too small for one sequence: "
-                        f"{self.kv.num_blocks} blocks of {self.block_size}"
-                    )
-                self._evict(victim)
-                if victim is req:
-                    break
-
-        reqs = [r if (r is not None and r.state == RUNNING) else None
-                for r in self._slots]
-        if not any(r is not None for r in reqs):
+        running = self._running
+        if not running:
             return
-        # a tick with any degraded request runs the WHOLE pool through the
-        # xla-compiled step (one compiled step per tick is the engine
-        # invariant; healthy rows are row-independent either way)
-        decode = (
-            self._get_decode_xla()
-            if any(r is not None and r.degraded for r in reqs)
-            else self._decode
-        )
-        logits, self.kv.pools = decode(
-            self.params, self.kv.pools,
-            jnp.asarray(self._cur), jnp.asarray(self._ctx),
-            jnp.asarray(self.kv.block_tables),
-        )
-        self._decode_steps += 1
-        rows = np.asarray(logits[:, -1])
+        with telemetry.span("engine.decode") as span:
+            # grow every running slot's table for the position it writes
+            # next; under exhaustion the LIFO victim is evicted until the
+            # rest fit (the last one standing is never evicted)
+            for req in sorted(running, key=lambda r: r.admit_index):
+                if req.state != RUNNING:
+                    continue
+                while not self._ensure(req.slot, int(self._ctx[req.slot]) + 1):
+                    victims = self._running
+                    victim = self.scheduler.pick_victim(victims)
+                    if victim is req and len(victims) == 1:
+                        raise RuntimeError(
+                            f"KV pool too small for one sequence: "
+                            f"{self.kv.num_blocks} blocks of {self.block_size}"
+                        )
+                    self._evict(victim)
+                    if victim is req:
+                        break
+
+            reqs = [r if (r is not None and r.state == RUNNING) else None
+                    for r in self._slots]
+            span.n = live = sum(r is not None for r in reqs)
+            # a tick with any degraded request runs the WHOLE pool through
+            # the xla-compiled step (one compiled step per tick is the engine
+            # invariant; healthy rows are row-independent either way)
+            decode = (
+                self._get_decode_xla()
+                if any(r is not None and r.degraded for r in reqs)
+                else self._decode
+            )
+            logits, self.kv.pools = decode(
+                self.params, self.kv.pools,
+                jnp.asarray(self._cur), jnp.asarray(self._ctx),
+                jnp.asarray(self.kv.block_tables),
+            )
+            self._decode_steps += 1
+        with telemetry.span("engine.fetch", n=live):
+            rows = np.asarray(logits[:, -1])
         next_tokens = self._sample_rows(rows, reqs)
         for i, req in enumerate(reqs):
             if req is None:
@@ -492,12 +524,13 @@ class Engine:
         """One engine tick (deadline sweep -> admit -> prefill chunk ->
         decode step).  Returns True while there is work left."""
         self._tick += 1
-        self._sweep_deadlines()
-        self._try_admit()
-        self._advance_prefill()
-        self._try_admit()    # a finished prefill may free the pipeline
-        self._decode_once()
-        return self._busy()
+        with telemetry.span("engine.step", n=self._tick):
+            self._sweep_deadlines()
+            self._try_admit()
+            self._advance_prefill()
+            self._try_admit()    # a finished prefill may free the pipeline
+            self._decode_once()
+            return self._busy()
 
     def run(self) -> Dict[int, List[int]]:
         """Drain the queue; returns {rid: generated tokens} and fills

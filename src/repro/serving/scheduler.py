@@ -63,6 +63,7 @@ class ServeRequest:
     rng: Optional[np.random.Generator] = None
     preemptions: int = 0
     arrival_s: float = 0.0
+    admit_s: Optional[float] = None          # first admission
     first_token_s: Optional[float] = None
     finish_s: Optional[float] = None
 
