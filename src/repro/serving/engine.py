@@ -12,7 +12,8 @@ scheduler.  ``step()`` advances the whole pool by one tick:
        chunk;
     3. **decode** — ONE compiled step serves every running slot (static
        shapes; free slots compute into the null block and are ignored), each
-       row sampled with its request's own params and seeded stream.
+       row sampled on the device with its request's own params and seeded
+       stream; only the (B,) tokens come back to the host.
 
 Because every slot attends only to its own blocks with its own positions,
 rows are independent: a greedy request's output is bit-identical whether it
@@ -21,8 +22,8 @@ runs alone or packed with arbitrary batch-mates — the property
 
 Each tick leaves host spans in ``serving.telemetry`` (``engine.step`` and,
 under it, admit / prefill / import / decode / fetch / sample), so the time
-the host spends drawing tokens and blocked on the device can be read apart
-(docs/serving.md).
+the host spends blocked on the device programs and on the token draw can be
+read apart (docs/serving.md).
 
 Under memory pressure (``ensure`` fails mid-decode) the scheduler's LIFO
 victim is evicted: blocks freed, request re-queued at the front carrying its
@@ -58,6 +59,11 @@ from repro.serving.scheduler import (
 )
 
 __all__ = ["Engine", "EngineConfig"]
+
+# the first-token row of a prefill chunk, picked at a traced position: one
+# program per chunk shape, none per prompt length
+_first_row = jax.jit(lambda logits, i: jax.lax.dynamic_index_in_dim(logits[0], i))
+_rows_finite = jax.jit(lambda rows: jnp.isfinite(rows).all(-1))
 
 
 def _named(fn, name: str):
@@ -162,6 +168,9 @@ class Engine:
         self._prefill_chunks = 0
         self._preempt_count = 0
         self._generated_total = 0
+        self._rows_greedy = 0
+        self._rows_drawn = 0
+        self._filtered_draws = 0
         self.last_stats: Dict[str, Any] = {}
         # reliability bookkeeping (docs/reliability.md §serving)
         self._tick = 0
@@ -201,7 +210,6 @@ class Engine:
         self._next_rid = max(self._next_rid, rid) + 1
         sp = sampling_params or SamplingParams()
         req = ServeRequest(rid=rid, prompt=prompt, sampling=sp, on_token=on_token)
-        req.rng = np.random.default_rng(sp.seed)
         req.arrival_s = time.monotonic()
         ttl = ttl_s if ttl_s is not None else self.ecfg.ttl_s
         if ttl is not None:
@@ -285,28 +293,33 @@ class Engine:
         self._cur[slot, 0] = token
         return False
 
-    def _sample_rows(self, logits: np.ndarray,
-                     reqs: List[Optional[ServeRequest]]) -> np.ndarray:
-        """One vectorized draw over the (B, V) logits; rows without a request
-        fall back to greedy and are ignored by the caller."""
+    def _sample_rows(self, logits, reqs: List[Optional[ServeRequest]]) -> np.ndarray:
+        """One device draw over the (B, V) logits rows, still on the device;
+        returns the (B,) tokens on the host.  Rows without a request fall
+        back to greedy and are ignored by the caller."""
         b, v = logits.shape
         live = [r for r in reqs if r is not None]
         rid = live[0].rid if b == 1 and live else -1
         with telemetry.span("engine.sample", rid=rid, n=len(live)):
             temp = np.zeros(b, np.float32)
-            top_k = np.zeros(b, np.int64)
+            top_k = np.zeros(b, np.int32)
             top_p = np.ones(b, np.float32)
-            uniforms = np.zeros((b, v), np.float64)
+            seeds = [0] * b
+            counters = np.zeros(b, np.int32)
             for i, r in enumerate(reqs):
                 if r is None:
                     continue
                 sp = r.sampling
                 temp[i], top_k[i], top_p[i] = sp.temperature, sp.top_k, sp.top_p
-                if sp.temperature > 0:
-                    uniforms[i] = r.rng.random(v)
+                seeds[i], counters[i] = sp.seed, len(r.generated)
+            draw, on_k, on_p = sampling.row_filters(temp, top_k, top_p, v)
+            drawn = int(draw.sum())
+            self._rows_drawn += drawn
+            self._rows_greedy += len(live) - drawn
+            self._filtered_draws += bool((on_k | on_p).any())
             return sampling.sample_tokens(
                 logits, temperature=temp, top_k=top_k, top_p=top_p,
-                uniforms=uniforms,
+                uniforms=sampling.RowSeeds(seeds, counters),
             )
 
     # ------------------------------------------------------------- faults --
@@ -449,13 +462,13 @@ class Engine:
         # first token: logits row of the prompt's last position within the
         # final prefill call (padded chunk: plen-1 relative to chunk start;
         # SSM single-token tail: the only row)
+        pos = (plen - 1) - (self._prefill_done - last_logits.shape[1])
         with telemetry.span("engine.fetch", rid=req.rid, n=1):
-            row = np.asarray(
-                last_logits[0, (plen - 1) - (self._prefill_done - last_logits.shape[1])])
-        if self.ecfg.verify and not np.isfinite(row).all():
+            row = _first_row(last_logits, np.int32(pos)).block_until_ready()
+        if self.ecfg.verify and not _rows_finite(row)[0]:
             self._handle_fault(req)
             return
-        tok = int(self._sample_rows(row[None], [req])[0])
+        tok = int(self._sample_rows(row, [req])[0])
         self._prefilling = None
         self._prefill_cache = None
         self._prefill_tokens = None
@@ -506,12 +519,14 @@ class Engine:
             )
             self._decode_steps += 1
         with telemetry.span("engine.fetch", n=live):
-            rows = np.asarray(logits[:, -1])
+            logits.block_until_ready()
+        rows = logits[:, -1]
         next_tokens = self._sample_rows(rows, reqs)
+        finite = np.asarray(_rows_finite(rows)) if self.ecfg.verify else None
         for i, req in enumerate(reqs):
             if req is None:
                 continue
-            if self.ecfg.verify and not np.isfinite(rows[i]).all():
+            if finite is not None and not finite[i]:
                 # corrupted KV / a tripped verified matmul surfaces here as a
                 # nonfinite logits row; only this row's request pays
                 self._handle_fault(req)
@@ -542,6 +557,9 @@ class Engine:
         wall = time.monotonic() - t0
         self.last_stats = {
             "decode_steps": self._decode_steps - steps0,
+            "sample_rows_greedy": self._rows_greedy,
+            "sample_rows_drawn": self._rows_drawn,
+            "sample_filtered_draws": self._filtered_draws,
             "wall_s": wall,
             "tok_per_s": (self._generated_total - gen0) / max(wall, 1e-9),
             "prefill_chunks": self._prefill_chunks,

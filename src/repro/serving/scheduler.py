@@ -60,7 +60,6 @@ class ServeRequest:
     slot: int = -1
     admit_index: int = -1                    # admission order (victim pick)
     generated: List[int] = dataclasses.field(default_factory=list)
-    rng: Optional[np.random.Generator] = None
     preemptions: int = 0
     arrival_s: float = 0.0
     admit_s: Optional[float] = None          # first admission

@@ -165,6 +165,136 @@ def test_seeded_sampling_is_packing_invariant():
         assert solo.run()[0] == packed[i], f"request {i} depends on packing"
 
 
+def _numpy_sampler(logits, temperature, top_k, top_p, uniforms):
+    """The host sampler the device draw replaced (stable argsort, float64
+    noise), kept as an oracle; returns the tokens and the noisy scores."""
+    logits = np.asarray(logits, np.float32)
+    b, v = logits.shape
+    greedy = temperature <= 0.0
+    scaled = logits / np.where(greedy, 1.0, temperature)[:, None]
+    order = np.argsort(-scaled, axis=-1, kind="stable")
+    ranks = np.empty_like(order)
+    np.put_along_axis(ranks, order, np.broadcast_to(np.arange(v), (b, v)), -1)
+    keep = ranks < np.where(top_k <= 0, v, top_k)[:, None]
+    p_mask = top_p < 1.0
+    if p_mask.any():
+        masked = np.where(keep, scaled, -np.inf)
+        probs = np.exp(masked - masked.max(-1, keepdims=True))
+        probs /= probs.sum(-1, keepdims=True)
+        p_sorted = np.take_along_axis(probs, order, -1)
+        cum = np.cumsum(p_sorted, -1)
+        keep_p = np.empty_like(keep)
+        np.put_along_axis(keep_p, order, (cum - p_sorted) < top_p[:, None], -1)
+        keep &= ~p_mask[:, None] | keep_p
+    g = -np.log(-np.log(np.clip(uniforms, 1e-20, np.nextafter(1.0, 0.0))))
+    noisy = np.where(keep, scaled, -np.inf) + g
+    return np.where(greedy, logits.argmax(-1), noisy.argmax(-1)), noisy
+
+
+@pytest.mark.parametrize("filters", ["top_k", "top_p", "top_k+top_p"])
+def test_device_sampler_matches_numpy_oracle(filters):
+    """Same float logits and uniforms: every greedy row agrees with the
+    numpy sampler, and every drawn row whose best two noisy scores are
+    more than 1e-4 apart (float32 noise against float64)."""
+    rng = np.random.default_rng(11)
+    b, v = 48, 300
+    for _ in range(8):
+        logits = (rng.normal(size=(b, v)) * 3).astype(np.float32)
+        temp = np.where(rng.random(b) < 0.3, 0.0,
+                        rng.uniform(0.3, 2.0, b)).astype(np.float32)
+        top_k = np.zeros(b, np.int64)
+        top_p = np.ones(b, np.float32)
+        if "top_k" in filters:
+            top_k = np.where(rng.random(b) < 0.7, rng.integers(1, 60, b), 0)
+        if "top_p" in filters:
+            top_p = np.where(rng.random(b) < 0.7, rng.uniform(0.05, 0.99, b),
+                             1.0).astype(np.float32)
+        u = rng.random((b, v))
+        want, noisy = _numpy_sampler(logits, temp, top_k, top_p, u)
+        got = sampling.sample_tokens(logits, temperature=temp, top_k=top_k,
+                                     top_p=top_p, uniforms=u)
+        assert got.dtype == np.int32 and got.shape == (b,)
+        best2 = np.sort(noisy, -1)[:, -2:]
+        decided = (temp <= 0) | (best2[:, 1] - best2[:, 0] > 1e-4)
+        assert decided.mean() > 0.9
+        assert (got == want)[decided].all(), np.flatnonzero((got != want) & decided)
+
+
+def test_device_draw_matches_nucleus_distribution():
+    """4,096 seeded draws of one 16-token row at T 0.8 / top-p 0.95 follow
+    the exact renormalized nucleus probabilities."""
+    n, v = 4096, 16
+    logits = np.random.default_rng(5).normal(size=v).astype(np.float32) * 1.5
+    probs = np.exp(logits / 0.8 - (logits / 0.8).max())
+    probs /= probs.sum()
+    order = np.argsort(-probs)
+    kept = order[:int(np.searchsorted(np.cumsum(probs[order]), 0.95)) + 1]
+    nucleus = np.zeros(v)
+    nucleus[kept] = probs[kept] / probs[kept].sum()
+    assert len(kept) < v                        # the filter removes tokens
+
+    tok = sampling.sample_tokens(
+        np.broadcast_to(logits, (n, v)), temperature=np.full(n, 0.8, np.float32),
+        top_k=np.zeros(n, np.int32), top_p=np.full(n, 0.95, np.float32),
+        uniforms=sampling.RowSeeds([2**33 + 17] * n, np.arange(n)))
+    freq = np.bincount(tok, minlength=v) / n
+    assert (freq[nucleus == 0] == 0).all()
+    assert np.abs(freq - nucleus).max() < 0.03, (freq, nucleus)
+
+
+def test_row_seeds_key_on_both_seed_words_and_the_counter():
+    u = sampling.device_uniforms([7, 7, 7 + 2**32, 7], [0, 1, 0, 0], 64)
+    u = np.asarray(u)
+    assert u.shape == (4, 64) and u.dtype == np.float32
+    assert ((0 <= u) & (u < 1)).all()
+    assert (u[0] == u[3]).all()                  # the same seed and index
+    assert not np.allclose(u[0], u[1])           # the next token's draw
+    assert not np.allclose(u[0], u[2])           # the seed's high word
+    # the documented stream: token n of a request keyed by fold_in(key(seed), n)
+    want = jax.random.uniform(jax.random.fold_in(jax.random.key(7), 1), (64,))
+    assert (u[1] == np.asarray(want)).all()
+
+
+def test_sampler_compiles_once_per_row_count():
+    """A greedy batch, then a top-p batch, run the program the greedy one
+    compiled: one cache entry per row count (the first token's one row and
+    the decode step's slots)."""
+    cfg = get_config("llama3_8b").reduced()
+    eng = Engine(cfg, _params(cfg), engine_cfg=EngineConfig(
+        slots=2, max_seq=32, prefill_chunk=8))
+    sampling._sample_seeded.clear_cache()
+    prompts = _prompts(cfg, 4)
+    for i in range(2):
+        eng.add_request(prompts[i], SamplingParams(max_new_tokens=3), rid=i)
+    eng.run()
+    assert sampling._sample_seeded._cache_size() == 2
+    for i in range(2, 4):
+        eng.add_request(prompts[i], SamplingParams(
+            temperature=0.8, top_p=0.9, max_new_tokens=3, seed=i), rid=i)
+    eng.run()
+    assert sampling._sample_seeded._cache_size() == 2
+    assert eng.last_stats["sample_filtered_draws"] > 0
+
+
+def test_sample_counters_on_a_mixed_batch():
+    cfg = get_config("llama3_8b").reduced()
+    eng = Engine(cfg, _params(cfg), engine_cfg=EngineConfig(
+        slots=3, max_seq=32, prefill_chunk=8, eos_id=-1))
+    prompts = _prompts(cfg, 3)
+    eng.add_request(prompts[0], SamplingParams(max_new_tokens=4), rid=0)
+    eng.add_request(prompts[1], SamplingParams(
+        temperature=0.7, max_new_tokens=4, seed=1), rid=1)
+    eng.add_request(prompts[2], SamplingParams(
+        temperature=0.7, top_k=5, max_new_tokens=4, seed=2), rid=2)
+    out = eng.run()
+    assert [len(out[i]) for i in range(3)] == [4, 4, 4]
+    st_ = eng.last_stats
+    assert st_["sample_rows_greedy"] == 4
+    assert st_["sample_rows_drawn"] == 8
+    # the top-k request's first token and its three decode steps
+    assert st_["sample_filtered_draws"] == 4
+
+
 # -------------------------------------------------- continuous batching -----
 @pytest.mark.parametrize("arch", FAMILIES)
 def test_continuous_greedy_matches_solo(arch):
@@ -211,6 +341,28 @@ def test_preemption_recovers_greedy_outputs():
         tight.add_request(p, sp, rid=i)
     got = tight.run()
     assert tight.last_stats["preemptions"] >= 1 and evicted
+    assert got == want
+
+
+def test_preempted_sampled_request_keeps_its_stream():
+    """A sampled request evicted and re-admitted draws the same tokens as
+    one never preempted: each draw is keyed by its seed and token index."""
+    cfg = get_config("llama3_8b").reduced()
+    params = _params(cfg)
+    prompts = _prompts(cfg, 3, lo=6, hi=10)
+    sp = [SamplingParams(temperature=0.9, top_p=0.9, max_new_tokens=8,
+                         seed=2**32 + i) for i in range(3)]
+
+    def serve(**kw):
+        eng = Engine(cfg, params, engine_cfg=EngineConfig(
+            slots=3, max_seq=32, prefill_chunk=8, eos_id=-1, **kw))
+        for i, p in enumerate(prompts):
+            eng.add_request(p, sp[i], rid=i)
+        return eng.run(), eng.last_stats["preemptions"]
+
+    want, _ = serve()
+    got, preempted = serve(block_size=4, num_blocks=11)
+    assert preempted >= 1
     assert got == want
 
 
@@ -367,12 +519,15 @@ print("SHARDED_SERVE_OK")
 """, devices=2)
 
 
-def test_gumbel_boundary_uniform_stays_finite():
+@pytest.mark.parametrize("dtype", [np.float64, np.float32])
+def test_gumbel_boundary_uniform_stays_finite(dtype):
     """Regression (pre-PR bug): the upper clip was ``1.0 - 1e-20``, which IS
     1.0 in float64 — a boundary uniform of exactly 1.0 produced +inf Gumbel
     noise that hijacked the argmax (and turned a top-k-masked lane into
-    inf + -inf = nan).  The clip must land strictly below 1.0."""
-    g = sampling.gumbel_from_uniform(np.array([0.0, 0.5, 1.0, np.nextafter(1.0, 2.0)]))
+    inf + -inf = nan).  The clip must land strictly below 1.0 in the float32
+    the noise is made in, where float64's largest value below 1.0 is 1.0."""
+    u = np.array([0.0, 0.5, np.nextafter(1.0, 0.0), 1.0, np.nextafter(1.0, 2.0)])
+    g = np.asarray(sampling.gumbel_from_uniform(u.astype(dtype)))
     assert np.isfinite(g).all(), g
 
     # end-to-end: one row fed u==1.0 everywhere must still draw from its
@@ -382,5 +537,5 @@ def test_gumbel_boundary_uniform_stays_finite():
     tok = sampling.sample_tokens(
         logits, temperature=np.ones(1, np.float32),
         top_k=np.full(1, 4, np.int64), top_p=np.ones(1, np.float32),
-        uniforms=np.ones((1, 16)))
+        uniforms=np.ones((1, 16), dtype))
     assert int(tok[0]) in range(4)
