@@ -11,7 +11,23 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
-__all__ = ["ArchConfig", "ShapeCell", "SHAPE_CELLS"]
+__all__ = ["ArchConfig", "ShapeCell", "SHAPE_CELLS", "Yarn"]
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rope scaling as DeepSeek-V2 publishes it (``rope_scaling`` of
+    type "yarn"): frequencies between the two correction bounds blend from
+    extrapolated to interpolated by ``factor``; ``mscale`` scales cos/sin
+    by ``m(mscale) / m(mscale_all_dim)`` and the softmax by
+    ``m(mscale_all_dim)**2``, with ``m(s) = 0.1 s ln(factor) + 1``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -30,6 +46,7 @@ class ArchConfig:
     qkv_bias: bool = False
     tie_embeddings: bool = False
     rope_theta: float = 10_000.0
+    rope_yarn: Optional[Yarn] = None  # None = plain RoPE
     norm_eps: float = 1e-5
     # MoE
     n_experts: int = 0
@@ -38,6 +55,14 @@ class ArchConfig:
     d_ff_expert: int = 0
     capacity_factor: float = 1.25
     router_aux_loss: float = 0.001
+    first_k_dense: int = 0           # leading layers with a dense FFN (d_ff)
+    norm_topk_prob: bool = True      # renormalize the top-k gates to sum 1
+    routed_scaling_factor: float = 1.0
+    # the routed experts this chip holds: experts [moe_first_expert,
+    # moe_first_expert + moe_held_experts) of the router's n_experts
+    # (0 = all of them); the layer computes only their part of the result
+    moe_held_experts: int = 0
+    moe_first_expert: int = 0
     # MLA (DeepSeek multi-head latent attention)
     use_mla: bool = False
     kv_lora_rank: int = 0
@@ -138,6 +163,11 @@ class ArchConfig:
         return self.n_experts > 0
 
     @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.moe_held_experts or self.n_experts
+
+    @property
     def is_ssm(self) -> bool:
         return self.ssm_state > 0 and self.attn_every == 0 and self.n_heads == 0
 
@@ -172,10 +202,16 @@ class ArchConfig:
             per_layer += d * (self.kv_lora_rank + self.qk_rope_head_dim)
             per_layer += self.kv_lora_rank * self.n_heads * (self.qk_nope_head_dim + self.v_head_dim)
             per_layer += self.n_heads * self.v_head_dim * d
+            per_layer += self.kv_lora_rank  # latent norm gain
+        dense_layers = 0
         if self.is_moe:
             per_layer += d * self.n_experts  # router
             per_layer += self.n_experts * 3 * d * self.d_ff_expert
             per_layer += self.n_shared_experts * 3 * d * self.d_ff_expert
+            # leading dense layers: a d_ff SwiGLU in place of the experts
+            dense_layers = self.first_k_dense * (
+                3 * d * self.d_ff - d * self.n_experts
+                - (self.n_experts + self.n_shared_experts) * 3 * d * self.d_ff_expert)
         elif self.d_ff:
             per_layer += 3 * d * self.d_ff
         if self.ssm_state:
@@ -187,7 +223,7 @@ class ArchConfig:
                 return total + self.n_layers * ssm + (
                     d * self.n_heads * hd * 2 + 2 * d * self.n_kv_heads * hd + 3 * d * self.d_ff
                 )
-        return total + self.n_layers * per_layer
+        return total + self.n_layers * per_layer + dense_layers
 
     def active_param_count(self) -> int:
         """Parameters touched per token (MoE: top-k + shared experts only)."""
@@ -195,7 +231,8 @@ class ArchConfig:
             return self.param_count()
         dense = self.param_count()
         expert = 3 * self.d_model * self.d_ff_expert
-        inactive = (self.n_experts - self.moe_top_k) * expert * self.n_layers
+        moe_layers = self.n_layers - self.first_k_dense
+        inactive = (self.n_experts - self.moe_top_k) * expert * moe_layers
         return dense - inactive
 
     def reduced(self, **overrides) -> "ArchConfig":
@@ -220,6 +257,8 @@ class ArchConfig:
             ssm_headdim=32 if self.ssm_state else self.ssm_headdim,
             ssm_chunk=32 if self.ssm_state else self.ssm_chunk,
             attn_every=min(self.attn_every, 2) if self.attn_every else 0,
+            moe_held_experts=0,
+            moe_first_expert=0,
             remat="none",
         )
         if self.attn_every:

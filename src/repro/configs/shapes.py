@@ -66,7 +66,8 @@ def linear_dims(cfg: ArchConfig) -> List[Tuple[str, int, int]]:
             sff = cfg.n_shared_experts * cfg.d_ff_expert
             add("shared_gate_up", d, sff)
             add("shared_down", sff, d)
-    elif cfg.d_ff and (not cfg.ssm_state or cfg.is_hybrid):
+    if (cfg.d_ff and (not cfg.is_moe or cfg.first_k_dense)
+            and (not cfg.ssm_state or cfg.is_hybrid)):     # leading dense layers too
         add("mlp_gate_up", d, cfg.d_ff)
         add("mlp_down", cfg.d_ff, d)
     if not cfg.tie_embeddings:

@@ -169,12 +169,12 @@ def pipeline_train_step_fn(cfg, optimizer, plan, *, n_micro: int,
         # crosses the shard_map boundary
         s = a.shape[1]
         positions = jnp.arange(s, dtype=jnp.int32)
-        rope_dim = cfg.qk_rope_head_dim if cfg.use_mla else cfg.resolved_head_dim
-        rope = layers.rope_tables(positions, rope_dim, cfg.rope_theta)
+        rope = tf_model._rope(cfg, positions)
 
         def blk(h, lp):
-            h, _, _ = tf_model._transformer_block(
-                h, lp, cfg, positions=positions, rope=rope, cache=None,
+            # uniform stages: the layer index only picks leading dense layers
+            h, _, _, _ = tf_model._transformer_block(
+                h, lp, 0, cfg, {}, positions=positions, rope=rope, cache=None,
                 kv_chunk=0, constrain=lambda v, _name: v, plan=None,
             )
             return h, None

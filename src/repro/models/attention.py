@@ -71,6 +71,7 @@ def attention_core(
                             # cost analysis counts every chunk (launch/dryrun)
     backend: Optional[str] = None,  # api.attention backend name; None = the
                                     # XLA dense/chunked paths below
+    scale: Optional[float] = None,  # softmax scale; None = D ** -0.5
 ) -> jax.Array:
     """Scaled-dot-product GQA attention, optionally KV-chunked.
 
@@ -88,7 +89,7 @@ def attention_core(
     b, sq, h, d = q.shape
     _, sk, kv, dv = v.shape
     groups = h // kv
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
     q = (q * scale).astype(q.dtype)
     # GQA: broadcast kv heads up to h.  The expanded form keeps one clean
     # head axis, which shards over the TP axis without the (kv, group)
@@ -255,6 +256,59 @@ def init_mla_cache(batch: int, max_seq: int, cfg, dtype) -> Dict:
     }
 
 
+def _mla_inputs(x, p, cfg, pos, rope, nk):
+    """q (B, S, H, nope + rope) with its rope half rotated, the normalized
+    latent c_kv (B, S, r) and the shared rotated k_rope (B, S, dr): what
+    both MLA forms start from.  ``pos`` broadcasts like ``rope``'s rows."""
+    b, s, _ = x.shape
+    h, dn, dr = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    q = layers.linear(x, p["wq"], **nk).reshape(b, s, h, dn + dr)
+    q_rope = layers.apply_rope(q[..., dn:], pos, cfg.rope_theta, tables=rope)
+    q = jnp.concatenate([q[..., :dn], q_rope], -1)
+    # kv_a_layernorm: the cache holds the normalized latent
+    c_kv = layers.rms_norm(layers.linear(x, p["w_dkv"], **nk), p["kv_norm"],
+                           cfg.norm_eps)
+    k_rope = layers.linear(x, p["w_krope"], **nk)
+    k_rope = layers.apply_rope(
+        k_rope[:, :, None, :], pos, cfg.rope_theta, tables=rope)[:, :, 0, :]
+    return q, c_kv, k_rope
+
+
+def _mla_up(p, cfg, dtype):
+    """Natural-layout (r, H, nope) and (r, H, v) up-projections: both MLA
+    forms contract them per head, so permutated/quantized storage cannot be
+    consumed directly."""
+    h, r = cfg.n_heads, cfg.kv_lora_rank
+    w_uk = _natural(p["w_uk"]).astype(dtype).reshape(r, h, cfg.qk_nope_head_dim)
+    w_uv = _natural(p["w_uv"]).astype(dtype).reshape(r, h, cfg.v_head_dim)
+    return w_uk, w_uv
+
+
+def _expand_prefix(c_kv, k_rope, w_uk, w_uv, live):
+    """Per-head K = [c_kv W_uk ; k_rope] (B, H, Smax, nope + rope) and
+    V = c_kv W_uv (B, H, Smax, v) of a contiguous latent cache, computed in
+    blocks over the ``live`` (traced) prefix only; rows past it stay 0."""
+    b, smax, _ = c_kv.shape
+    h, dn, dv = w_uk.shape[1], w_uk.shape[2], w_uv.shape[2]
+    dr = k_rope.shape[-1]
+    blk = next((n for n in (2048, 1024, 512, 256, 128) if smax % n == 0), smax)
+
+    def body(i, kv):
+        k, v = kv
+        c = jax.lax.dynamic_slice_in_dim(c_kv, i * blk, blk, axis=1)
+        kr = jax.lax.dynamic_slice_in_dim(k_rope, i * blk, blk, axis=1)
+        kb = jnp.concatenate([
+            jnp.einsum("bsr,rhd->bhsd", c, w_uk),
+            jnp.broadcast_to(kr[:, None], (b, h, blk, dr)).astype(c.dtype)], -1)
+        vb = jnp.einsum("bsr,rhd->bhsd", c, w_uv)
+        return (jax.lax.dynamic_update_slice_in_dim(k, kb, i * blk, axis=2),
+                jax.lax.dynamic_update_slice_in_dim(v, vb, i * blk, axis=2))
+
+    zeros = (jnp.zeros((b, h, smax, dn + dr), c_kv.dtype),
+             jnp.zeros((b, h, smax, dv), c_kv.dtype))
+    return jax.lax.fori_loop(0, (live + blk - 1) // blk, body, zeros)
+
+
 def mla_attention(
     x: jax.Array,
     p: Dict,
@@ -269,26 +323,28 @@ def mla_attention(
     rope=None,                     # precomputed layers.rope_tables (hoisted)
     residual: Optional[jax.Array] = None,  # fused into the out-projection
     norm: Optional[jax.Array] = None,  # attn_norm gain fused as a prologue
-    attn_backend: Optional[str] = None,  # api.attention backend (prefill only;
-                                         # absorbed decode stays latent-space)
+    attn_backend: Optional[str] = None,  # api.attention backend (e.g. "flash")
 ) -> Tuple[jax.Array, Optional[Dict]]:
-    """DeepSeek-V2 multi-head latent attention.
+    """DeepSeek-V2 multi-head latent attention, expanded form.
 
-    Params: wq -> (d, H*(nope+rope)); w_dkv -> (d, kv_lora); w_krope -> (d, rope);
-    w_uk -> (kv_lora, H*nope); w_uv -> (kv_lora, H*v_dim); wo -> (H*v_dim, d).
+    Params: wq -> (d, H*(nope+rope)); w_dkv -> (d, kv_lora) with its RMSNorm
+    gain kv_norm; w_krope -> (d, rope); w_uk -> (kv_lora, H*nope);
+    w_uv -> (kv_lora, H*v_dim); wo -> (H*v_dim, d).
 
-    Prefill computes the naive (expanded) form; decode uses the *absorbed*
-    form — scores against the latent cache directly, never materializing
-    per-head K/V over the full context:
-
-        score = q_nope @ W_uk (absorbed into q)  ·  c_kv   +   q_rope · k_rope
-        out   = (probs @ c_kv) @ W_uv
+    Per-head K = [c_kv W_uk ; k_rope] and V = c_kv W_uv are materialized and
+    the heads attend as in MHA (query width nope+rope, value width v_dim),
+    with the softmax scale of ``layers.attention_scale`` (YaRN's mscale).
+    With a contiguous cache (chunked prefill) the chunk's latent is written
+    first and K/V are expanded over the cached prefix plus the chunk
+    (``_expand_prefix``); ``attn_backend`` ("flash" for serving) attends at
+    the traced offset ``pos``.  Paged decode takes the absorbed form instead
+    (``paged_mla_attention``).
     """
     constrain = layers.resolve_constrain(plan, constrain)
     b, s, _ = x.shape
-    h = cfg.n_heads
-    dn, dr, dv_ = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    r = cfg.kv_lora_rank
+    h, dv_ = cfg.n_heads, cfg.v_head_dim
+    qd = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    scale = layers.attention_scale(qd, cfg.rope_yarn)
     lk = dict(backend=cfg.matmul_backend, compute_dtype=x.dtype)
     # fused attn_norm (see gqa_attention): every projection reading x
     # normalizes it in its kernel's load stage
@@ -296,52 +352,35 @@ def mla_attention(
         lk, prologue="rmsnorm", prologue_operands=(norm,),
         prologue_eps=cfg.norm_eps,
     )
-
-    q = layers.linear(x, p["wq"], **nk).reshape(b, s, h, dn + dr)
-    q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = layers.apply_rope(q_rope, positions, cfg.rope_theta, tables=rope)
-
-    c_kv = layers.linear(x, p["w_dkv"], **nk)                               # (B,S,r)
-    k_rope = layers.linear(x, p["w_krope"], **nk)                           # (B,S,dr) shared
-    k_rope = layers.apply_rope(
-        k_rope[:, :, None, :], positions, cfg.rope_theta, tables=rope
-    )[:, :, 0, :]
-
-    # the absorbed form contracts these per-head — natural layout required
-    w_uk = _natural(p["w_uk"]).astype(x.dtype).reshape(r, h, dn)
-    w_uv = _natural(p["w_uv"]).astype(x.dtype).reshape(r, h, dv_)
+    q, c_kv, k_rope = _mla_inputs(x, p, cfg, positions, rope, nk)
+    w_uk, w_uv = _mla_up(p, cfg, x.dtype)
 
     if cache is None:
-        # naive/expanded prefill: materialize per-head K and V
-        k_nope = jnp.einsum("bsr,rhd->bshd", c_kv, w_uk)
+        k = jnp.concatenate([
+            jnp.einsum("bsr,rhd->bshd", c_kv, w_uk),
+            jnp.broadcast_to(k_rope[:, :, None, :], (b, s, h, k_rope.shape[-1]))], -1)
         v = jnp.einsum("bsr,rhd->bshd", c_kv, w_uv)
-        k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (b, s, h, dr))], -1)
-        qc = jnp.concatenate([q_nope, q_rope], -1)
-        qc, k, v = constrain(qc, "q_bthd"), constrain(k, "q_bthd"), constrain(v, "q_bthd")
-        out = attention_core(qc, k, v, positions, positions, kv_chunk=kv_chunk,
+        q, k, v = constrain(q, "q_bthd"), constrain(k, "q_bthd"), constrain(v, "q_bthd")
+        out = attention_core(q, k, v, positions, positions, kv_chunk=kv_chunk,
                              constrain=constrain, unroll=unroll,
-                             backend=attn_backend)
+                             backend=attn_backend, scale=scale)
         new_cache = None
     else:
         pos = cache["pos"]
-        cc = jax.lax.dynamic_update_slice(cache["c_kv"], c_kv, (0, pos, 0))
-        cr = jax.lax.dynamic_update_slice(cache["k_rope"], k_rope, (0, pos, 0))
+        cc = jax.lax.dynamic_update_slice(cache["c_kv"], c_kv.astype(cache["c_kv"].dtype),
+                                          (0, pos, 0))
+        cr = jax.lax.dynamic_update_slice(cache["k_rope"], k_rope.astype(cache["k_rope"].dtype),
+                                          (0, pos, 0))
         cc, cr = constrain(cc, "cache_bsr"), constrain(cr, "cache_bsr")
-        max_seq = cc.shape[1]
-        k_pos = jnp.arange(max_seq, dtype=jnp.int32)
-        live = (k_pos < pos + s)[None, None, None, :]
-
-        # absorbed decode
-        q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w_uk)                  # (B,S,H,r)
-        scale = (dn + dr) ** -0.5
-        s_lat = jnp.einsum("bshr,btr->bhst", q_lat.astype(jnp.float32), cc.astype(jnp.float32))
-        s_rope = jnp.einsum("bshd,btd->bhst", q_rope.astype(jnp.float32), cr.astype(jnp.float32))
-        scores = (s_lat + s_rope) * scale + _causal_mask(positions, k_pos)[None, None]
-        scores = jnp.where(live, scores, NEG_INF)
-        scores = constrain(scores, "scores")
-        probs = jax.nn.softmax(scores, axis=-1)
-        out_lat = jnp.einsum("bhst,btr->bshr", probs.astype(cc.dtype), cc)  # (B,S,H,r)
-        out = jnp.einsum("bshr,rhd->bshd", out_lat, w_uv)
+        k, v = _expand_prefix(cc.astype(x.dtype), cr.astype(x.dtype), w_uk, w_uv, pos + s)
+        smax = k.shape[2]
+        out = api.attention(
+            jnp.moveaxis(q, 2, 1).reshape(b * h, s, qd),
+            k.reshape(b * h, smax, qd), v.reshape(b * h, smax, dv_),
+            backend=attn_backend or "xla", causal=True, q_offset=pos,
+            kv_len=pos + s, scale=scale,
+        )
+        out = jnp.moveaxis(out.reshape(b, h, s, dv_), 1, 2).astype(x.dtype)
         new_cache = {"c_kv": cc, "k_rope": cr, "pos": pos + s}
 
     out = out.reshape(b, s, h * dv_)
@@ -541,30 +580,21 @@ def paged_mla_attention(
     residual: Optional[jax.Array] = None,
     norm: Optional[jax.Array] = None,  # attn_norm gain fused as a prologue
 ) -> Tuple[jax.Array, Dict]:
-    """Absorbed-form MLA decode against the paged latent pool (the compressed
-    c_kv / shared k_rope page exactly like K/V — one row per token)."""
+    """Absorbed-form MLA decode against the paged latent pool (the normalized
+    latent c_kv and the shared k_rope page exactly like K/V — one row per
+    token): scores are q_nope W_uk · c_kv + q_rope · k_rope and the output
+    (probs · c_kv) W_uv, so per-head K/V are never formed over the context."""
     constrain = constrain if constrain is not None else _id
     b, s, _ = x.shape
-    h = cfg.n_heads
-    dn, dr, dv_ = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    r = cfg.kv_lora_rank
+    h, dn, dv_ = cfg.n_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
     bs = cache["c_kv"].shape[1]
     lk = dict(backend=cfg.matmul_backend, compute_dtype=x.dtype)
     nk = dict(lk) if norm is None else dict(
         lk, prologue="rmsnorm", prologue_operands=(norm,),
         prologue_eps=cfg.norm_eps,
     )
-
-    q = layers.linear(x, p["wq"], **nk).reshape(b, s, h, dn + dr)
+    q, c_kv, k_rope = _mla_inputs(x, p, cfg, positions[:, None], rope, nk)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    pos2 = positions[:, None]
-    q_rope = layers.apply_rope(q_rope, pos2, cfg.rope_theta, tables=rope)
-
-    c_kv = layers.linear(x, p["w_dkv"], **nk)                   # (B, 1, r)
-    k_rope = layers.linear(x, p["w_krope"], **nk)               # (B, 1, dr)
-    k_rope = layers.apply_rope(
-        k_rope[:, :, None, :], pos2, cfg.rope_theta, tables=rope
-    )[:, :, 0, :]
 
     phys = block_tables[jnp.arange(b), positions // bs] * bs + positions % bs
     cc, ccs = paged_write(cache["c_kv"], phys, c_kv[:, 0],
@@ -579,17 +609,15 @@ def paged_mla_attention(
     cc_all = paged_read(cc, idx, scale_pool=ccs, dtype=x.dtype)  # (B, Smax, r)
     cr_all = paged_read(cr, idx, scale_pool=crs, dtype=x.dtype)  # (B, Smax, dr)
     smax = cc_all.shape[1]
+    w_uk, w_uv = _mla_up(p, cfg, x.dtype)
 
-    w_uk = _natural(p["w_uk"]).astype(x.dtype).reshape(r, h, dn)
-    w_uv = _natural(p["w_uv"]).astype(x.dtype).reshape(r, h, dv_)
-
+    # absorbed: W_uk folds into the query, W_uv applies after the latent sum
     q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w_uk)
-    scale = (dn + dr) ** -0.5
     s_lat = jnp.einsum("bshr,btr->bhst", q_lat.astype(jnp.float32),
                        cc_all.astype(jnp.float32))
     s_rope = jnp.einsum("bshd,btd->bhst", q_rope.astype(jnp.float32),
                         cr_all.astype(jnp.float32))
-    scores = (s_lat + s_rope) * scale
+    scores = (s_lat + s_rope) * layers.attention_scale(q.shape[-1], cfg.rope_yarn)
     live = (jnp.arange(smax, dtype=jnp.int32)[None, :] <= positions[:, None])
     scores = jnp.where(live[:, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)

@@ -10,6 +10,7 @@ format flags or hand-threaded ``d_out`` here.
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Union
 
 import jax
@@ -25,6 +26,8 @@ __all__ = [
     "swiglu",
     "rope_frequencies",
     "rope_tables",
+    "yarn_mscale",
+    "attention_scale",
     "apply_rope",
     "cross_entropy_loss",
 ]
@@ -114,23 +117,63 @@ def swiglu(gate: jax.Array, up: jax.Array) -> jax.Array:
     return jax.nn.silu(gate) * up
 
 
-def rope_frequencies(head_dim: int, theta: float) -> np.ndarray:
-    """Inverse frequencies for rotary embeddings (host constant)."""
-    return 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term ``0.1 mscale ln(factor) + 1``."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
-def rope_tables(positions: jax.Array, head_dim: int, theta: float):
+def attention_scale(q_dim: int, yarn=None) -> float:
+    """Softmax scale ``q_dim ** -0.5``; under YaRN times
+    ``yarn_mscale(factor, mscale_all_dim) ** 2`` (DeepSeek-V2)."""
+    scale = q_dim ** -0.5
+    if yarn is not None:
+        scale *= yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
+    return scale
+
+
+def rope_frequencies(head_dim: int, theta: float, yarn=None) -> np.ndarray:
+    """Inverse frequencies for rotary embeddings (host constant).
+
+    With ``yarn`` (``configs.base.Yarn``) the frequencies follow DeepSeek-V2's
+    ``yarn_find_correction_range``: those that turn fewer than ``beta_slow``
+    times over the original context are divided by ``factor``, those that
+    turn more than ``beta_fast`` times are kept, with a linear ramp between.
+    """
+    inv = 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
+    if yarn is None:
+        return inv
+
+    def bound(rotations):
+        return head_dim * math.log(
+            yarn.original_max_position / (rotations * 2 * math.pi)
+        ) / (2 * math.log(theta))
+
+    low = max(math.floor(bound(yarn.beta_fast)), 0)
+    high = min(math.ceil(bound(yarn.beta_slow)), head_dim - 1)
+    high = high + 0.001 if high == low else high
+    ramp = np.clip((np.arange(head_dim // 2) - low) / (high - low), 0.0, 1.0)
+    return inv / yarn.factor * ramp + inv * (1.0 - ramp)
+
+
+def rope_tables(positions: jax.Array, head_dim: int, theta: float, yarn=None):
     """``(cos, sin)`` rotation tables for the given absolute positions.
 
     Computed ONCE per forward and threaded through every layer — the angle
     table and its cos/sin are position-only, so recomputing them per layer
     (the historical ``apply_rope`` behavior) was n_layers-1 redundant
     transcendental sweeps per step.  Shapes broadcast over heads:
-    (..., seq, 1, head_dim/2), float32.
+    (..., seq, 1, head_dim/2), float32.  ``yarn`` scales the frequencies
+    (``rope_frequencies``) and cos/sin by ``m(mscale) / m(mscale_all_dim)``.
     """
-    inv_freq = jnp.asarray(rope_frequencies(head_dim, theta), dtype=jnp.float32)
+    inv_freq = jnp.asarray(rope_frequencies(head_dim, theta, yarn), dtype=jnp.float32)
     angles = positions.astype(jnp.float32)[..., None] * inv_freq  # (..., seq, hd/2)
-    return jnp.cos(angles)[..., None, :], jnp.sin(angles)[..., None, :]
+    cos, sin = jnp.cos(angles)[..., None, :], jnp.sin(angles)[..., None, :]
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
+    return cos, sin
 
 
 def apply_rope(

@@ -10,6 +10,15 @@ results are gathered back and combined with renormalized gates.  Tokens past
 an expert's per-group capacity C = ceil(S*k*cf / E) are dropped (standard
 GShard/Switch semantics, applied per group) — the drop COUNT is returned so
 callers can assert capacity is ample (``moe_ffn`` -> (out, aux, dropped)).
+That is training's dispatch.  Inference drops nothing: ``routed_ffn(...,
+dropless=True)`` sorts the (token, slot) pairs by expert and runs each
+expert weight as one grouped matmul (``jax.lax.ragged_dot``) over exactly
+the routed rows.
+
+Both compute only the experts this chip holds (``cfg.held_experts`` from
+``cfg.moe_first_expert``, ``model-configs`` §4): the router still scores
+all ``n_experts``, and a slot routed to an expert held elsewhere adds
+nothing here.  Every path returns the (routed, rows, dropped) counts.
 
 Sharding has two modes, decided by the :class:`~repro.distributed.plan.
 ShardingPlan` threaded through ``plan=``:
@@ -42,7 +51,7 @@ from repro.models import layers
 Constrain = Callable[[jax.Array, str], jax.Array]
 _id: Constrain = lambda x, tag: x
 
-__all__ = ["moe_ffn", "dense_ffn", "moe_capacity"]
+__all__ = ["moe_ffn", "routed_ffn", "dense_ffn", "moe_capacity"]
 
 
 def dense_ffn(
@@ -89,23 +98,44 @@ def moe_capacity(tokens: int, cfg) -> int:
 # routing / dispatch / combine building blocks — shared by the dense-style
 # path (global arrays, GSPMD shards them) and the EP shard_map body (local
 # shards, collectives placed by hand)
+def _gates(x: jax.Array, router: jax.Array, cfg):
+    """fp32 router softmax over all ``n_experts`` and its top-k: (logits,
+    probs, gates, ids).  The gates are renormalized to sum 1
+    (``norm_topk_prob``) or else scaled by ``routed_scaling_factor``, as
+    DeepSeek-V2's gate does."""
+    logits = jnp.einsum(
+        "...d,de->...e", x.astype(jnp.float32), router.astype(jnp.float32)
+    )
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, ids = jax.lax.top_k(probs, cfg.moe_top_k)
+    if cfg.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    else:
+        gates = gates * cfg.routed_scaling_factor
+    return logits, probs, gates, ids
+
+
+def _held(ids: jax.Array, cfg):
+    """Each slot's expert as an index into the experts held here, and
+    whether it is held (``moe_first_expert`` .. + ``held_experts``)."""
+    local = ids - cfg.moe_first_expert
+    return local, (local >= 0) & (local < cfg.held_experts)
+
+
 def _route(x: jax.Array, router: jax.Array, cfg, cap: int) -> Dict[str, jax.Array]:
     """Group-local routing state for ``x`` (G groups of S tokens each).
 
-    fp32 router softmax + top-k with renormalized gates, the Switch
-    load-balance + z-loss aux, and the sort-by-expert dispatch order
-    (gather-only — GSPMD partitions batched take_along_axis gathers along
-    the group dim, but replicates multi-index scatters; §Perf pair-2).
-    ``dropped`` counts (token, slot) pairs past an expert's capacity."""
+    fp32 router softmax + top-k (``_gates``), the Switch load-balance +
+    z-loss aux, and the sort-by-expert dispatch order over the experts held
+    here (gather-only — GSPMD partitions batched take_along_axis gathers
+    along the group dim, but replicates multi-index scatters; §Perf
+    pair-2).  Slots routed to experts held elsewhere sort last with gate 0.
+    ``dropped`` counts held (token, slot) pairs past an expert's capacity;
+    ``stats`` is (routed to held experts, buffer rows, dropped)."""
     g, sl, d = x.shape
-    e, k = cfg.n_experts, cfg.moe_top_k
+    e, k, e_h = cfg.n_experts, cfg.moe_top_k, cfg.held_experts
     cd = x.dtype
-    logits = jnp.einsum(
-        "bsd,de->bse", x.astype(jnp.float32), router.astype(jnp.float32)
-    )                                                            # (G, S, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, ids = jax.lax.top_k(probs, k)                         # (G, S, k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    logits, probs, gates, ids = _gates(x, router, cfg)           # (G, S, k)
 
     # load-balance loss (Switch): E * mean(frac_tokens_e * mean_prob_e)
     load = jax.nn.one_hot(ids[..., 0], e, dtype=jnp.float32).mean((0, 1))
@@ -113,8 +143,9 @@ def _route(x: jax.Array, router: jax.Array, cfg, cap: int) -> Dict[str, jax.Arra
     aux = cfg.router_aux_loss * e * jnp.sum(load * importance)
     aux = aux + 1e-4 * jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
 
-    flat_ids = ids.reshape(g, sl * k)                            # slot-major
-    gates_flat = gates.reshape(g, sl * k).astype(cd)
+    local, held = _held(ids, cfg)
+    flat_ids = jnp.where(held, local, e_h).reshape(g, sl * k)    # slot-major
+    gates_flat = jnp.where(held, gates, 0.0).reshape(g, sl * k).astype(cd)
     order = jnp.argsort(flat_ids, axis=1)                        # stable
     inv_order = jnp.argsort(order, axis=1)
     sorted_ids = jnp.take_along_axis(flat_ids, order, axis=1)
@@ -122,19 +153,21 @@ def _route(x: jax.Array, router: jax.Array, cfg, cap: int) -> Dict[str, jax.Arra
     sorted_src = jnp.take_along_axis(src, order[..., None], axis=1)
 
     # expert run boundaries within each group
-    erange = jnp.arange(e, dtype=jnp.int32)
+    erange = jnp.arange(e_h, dtype=jnp.int32)
     start = jax.vmap(
         lambda row: jnp.searchsorted(row, erange, side="left")
     )(sorted_ids)
     end = jax.vmap(
         lambda row: jnp.searchsorted(row, erange, side="right")
     )(sorted_ids)
-    counts = end - start                                         # (G, E)
+    counts = end - start                                         # (G, E_h)
     dropped = jnp.maximum(counts - cap, 0).sum().astype(jnp.int32)
+    stats = jnp.stack([counts.sum().astype(jnp.int32),
+                       jnp.int32(g * e_h * cap), dropped])
     return dict(
         gates_flat=gates_flat, order=order, inv_order=inv_order,
         sorted_ids=sorted_ids, sorted_src=sorted_src, start=start,
-        counts=counts, aux=aux, dropped=dropped,
+        counts=counts, aux=aux, dropped=dropped, stats=stats,
     )
 
 
@@ -159,9 +192,12 @@ def _combine(y: jax.Array, r: Dict[str, jax.Array], cap: int, k: int) -> jax.Arr
     cd = y.dtype
     sk = r["sorted_ids"].shape[1]
     j_iota = jnp.arange(sk, dtype=jnp.int32)[None, :]
-    pos_sorted = j_iota - jnp.take_along_axis(r["start"], r["sorted_ids"], axis=1)
-    keep_sorted = pos_sorted < cap
-    slot = r["sorted_ids"] * cap + jnp.where(keep_sorted, pos_sorted, 0)
+    ids = r["sorted_ids"]                       # == e for slots held elsewhere
+    held = ids < e
+    ids = jnp.minimum(ids, e - 1)
+    pos_sorted = j_iota - jnp.take_along_axis(r["start"], ids, axis=1)
+    keep_sorted = (pos_sorted < cap) & held
+    slot = ids * cap + jnp.where(keep_sorted, pos_sorted, 0)
     out_sorted = jnp.take_along_axis(
         y.reshape(g, e * cap, d), slot[..., None], axis=1
     ) * keep_sorted[..., None].astype(cd)
@@ -182,20 +218,35 @@ def moe_ffn(
     x: jax.Array, p: Dict, cfg, *, plan=None,
     constrain: Optional[Constrain] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Routed expert FFN.  Returns (output, aux_loss, dropped_token_count).
+    """Routed expert FFN with capacity semantics.  Returns (output,
+    aux_loss, dropped_token_count): ``routed_ffn`` with its stats cut to
+    the drop count."""
+    out, aux, stats = routed_ffn(x, p, cfg, plan=plan, constrain=constrain)
+    return out, aux, stats[2]
 
-    ``dropped`` is the number of (token, slot) pairs past capacity this
-    layer (int32 scalar) — zero when ``capacity_factor`` is ample; the
-    conformance tests assert it stays zero where exact parity is claimed.
 
-    Dispatch is *grouped by sequence*: every routing tensor (one-hot, cumsum,
-    scatter/gather indices) carries the batch dim, so under the sharding
-    policy all routing math is shard-local (B over DP), the (B, E, C, d)
-    expert buffers shard E over TP, and the only cross-device movement is
-    the unavoidable token<->expert exchange GSPMD derives from the batched
-    scatter/gather.  When the plan carries an :attr:`~repro.distributed.
-    plan.ShardingPlan.expert_plan` (strategy "ep") the exchange is instead
-    placed by hand: see :func:`_moe_ffn_ep`.
+def routed_ffn(
+    x: jax.Array, p: Dict, cfg, *, plan=None,
+    constrain: Optional[Constrain] = None, dropless: bool = False,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Routed expert FFN over the experts held here, plus the shared
+    experts.  Returns (output, aux_loss, stats) with stats the int32 triple
+    (routed, rows, dropped): (token, slot) pairs routed to held experts,
+    expert rows computed, and pairs past capacity.
+
+    The router scores all ``n_experts``; only the ``held_experts`` whose
+    weights this chip holds are computed, and the slots routed elsewhere
+    add nothing (the part the other chips of an expert-parallel group
+    give).  ``dropless`` (inference) runs ``_dropless``: every held pair is
+    computed, none dropped.  Otherwise (training) dispatch is *grouped by
+    sequence* with capacity ``moe_capacity``: every routing tensor
+    (one-hot, cumsum, scatter/gather indices) carries the batch dim, so
+    under the sharding policy all routing math is shard-local (B over DP),
+    the (B, E, C, d) expert buffers shard E over TP, and the only
+    cross-device movement is the unavoidable token<->expert exchange GSPMD
+    derives from the batched scatter/gather.  When the plan carries an
+    :attr:`~repro.distributed.plan.ShardingPlan.expert_plan` (strategy
+    "ep") the exchange is instead placed by hand: see :func:`_moe_ffn_ep`.
     """
     eplan = getattr(plan, "expert_plan", None)
     if eplan is not None and eplan.mesh is not None:
@@ -206,28 +257,61 @@ def moe_ffn(
                                constrain=constrain)
 
     constrain = layers.resolve_constrain(plan, constrain)
-    b, s, d = x.shape
-    cd = x.dtype
-    cap = moe_capacity(s, cfg)                                   # per-group capacity
+    if dropless:
+        out, stats = _dropless(x, p, cfg)
+        aux = jnp.zeros((), jnp.float32)
+    else:
+        s, cd = x.shape[1], x.dtype
+        cap = moe_capacity(s, cfg)                               # per-group capacity
 
-    r = _route(x, p["router"], cfg, cap)
-    buf = constrain(_fill_buffer(r, cap), "expert_buf")
+        r = _route(x, p["router"], cfg, cap)
+        buf = constrain(_fill_buffer(r, cap), "expert_buf")
 
-    # ---- batched per-expert SwiGLU: weights (E, d, ffe) / (E, ffe, d) ----
-    gate_h = jnp.einsum("becd,edf->becf", buf, p["w_gate"].astype(cd))
-    up_h = jnp.einsum("becd,edf->becf", buf, p["w_up"].astype(cd))
-    h = layers.swiglu(gate_h, up_h)
-    h = constrain(h, "expert_hidden")
-    y = jnp.einsum("becf,efd->becd", h, p["w_down"].astype(cd))  # (B, E, C, d)
-    y = constrain(y, "expert_buf")
+        # ---- batched per-expert SwiGLU: weights (E, d, ffe) / (E, ffe, d) ----
+        gate_h = jnp.einsum("becd,edf->becf", buf, p["w_gate"].astype(cd))
+        up_h = jnp.einsum("becd,edf->becf", buf, p["w_up"].astype(cd))
+        h = layers.swiglu(gate_h, up_h)
+        h = constrain(h, "expert_hidden")
+        y = jnp.einsum("becf,efd->becd", h, p["w_down"].astype(cd))  # (B, E, C, d)
+        y = constrain(y, "expert_buf")
 
-    out = _combine(y, r, cap, cfg.moe_top_k)
+        out = _combine(y, r, cap, cfg.moe_top_k)
+        aux, stats = r["aux"], r["stats"]
 
     # shared experts (DeepSeek-style), computed densely for every token
     shared = _shared_params(p)
     if cfg.n_shared_experts and shared is not None:
         out = out + dense_ffn(x, shared, cfg, constrain=constrain)
-    return constrain(out, "act_btd"), r["aux"], r["dropped"]
+    return constrain(out, "act_btd"), aux, stats
+
+
+def _dropless(x: jax.Array, p: Dict, cfg) -> Tuple[jax.Array, jax.Array]:
+    """Every (token, slot) pair routed to a held expert, computed: the
+    pairs sorted by expert run as ONE grouped matmul per weight
+    (``jax.lax.ragged_dot``, a native grouped kernel on TPU), whose rows
+    are exactly the routed pairs.  Pairs of experts held elsewhere sort
+    last, outside every group, and add nothing.  Returns (output, stats)."""
+    b, s, d = x.shape
+    t, k, e_h = b * s, cfg.moe_top_k, cfg.held_experts
+    cd = x.dtype
+    xf = x.reshape(t, d)
+    _, _, gates, ids = _gates(xf, p["router"], cfg)              # (T, k)
+    local, held = _held(ids.reshape(t * k), cfg)
+    key = jnp.where(held, local, e_h)
+    order = jnp.argsort(key)                                     # stable
+    sizes = jnp.bincount(key, length=e_h + 1)[:e_h].astype(jnp.int32)
+    rows = xf[order // k]                                        # (T*k, d)
+    h = layers.swiglu(
+        jax.lax.ragged_dot(rows, p["w_gate"].astype(cd), sizes),
+        jax.lax.ragged_dot(rows, p["w_up"].astype(cd), sizes))
+    y = jax.lax.ragged_dot(h, p["w_down"].astype(cd), sizes)
+    y = y[jnp.argsort(order)]                                    # slot order
+    # rows outside every group are not defined by the grouped matmul
+    y = jnp.where(held[:, None], y.astype(jnp.float32), 0.0)
+    out = (y * gates.reshape(t * k, 1)).reshape(t, k, d).sum(1)
+    routed = sizes.sum()
+    stats = jnp.stack([routed, routed, jnp.int32(0)])
+    return out.reshape(b, s, d).astype(cd), stats
 
 
 # --------------------------------------------------------------------------
@@ -262,10 +346,14 @@ def _moe_ffn_ep(
         6. ONE psum folding (aux, dropped) stats.
 
     Exactly TWO all-to-alls per MoE layer — the jaxpr contract the fleet
-    validator and the multidevice suite assert.
+    validator and the multidevice suite assert.  Returns (output, aux,
+    stats) as ``routed_ffn`` does.
     """
     from repro.kernels.dip_matmul_sharded import _inner_backend, _local_weight
 
+    if cfg.held_experts != cfg.n_experts:
+        raise ValueError("expert parallelism shards the whole expert bank; "
+                         "moe_held_experts does not compose with it")
     mesh, ax = eplan.mesh, eplan.axis
     t = mesh.shape[ax]
     b, s, d = x.shape
@@ -358,4 +446,7 @@ def _moe_ffn_ep(
         out_specs=(xspec, P(), P()),
     )(x, p["router"], banks, s_datas, s_scales)
     constrain = layers.resolve_constrain(plan, constrain)
-    return constrain(out, "act_btd"), aux, dropped
+    pairs, groups = b * s * k, b * (1 if batch_split else t)
+    stats = jnp.stack([jnp.int32(pairs), jnp.int32(groups * e * cap),
+                       dropped.astype(jnp.int32)])
+    return constrain(out, "act_btd"), aux, stats

@@ -40,6 +40,7 @@ __all__ = [
     "param_specs",
     "quantize_params",
     "forward",
+    "forward_counted",
     "init_cache",
     "init_paged_cache",
     "loss_fn",
@@ -123,6 +124,7 @@ def param_template(cfg) -> Dict[str, Any]:
         ).items():
             shape, fan, dip = _lin(cfg, di, do)
             blk[nm] = stacked(shape, fan, L, dip)
+        blk["kv_norm"] = stacked((rr,), None, L)       # kv_a_layernorm gain
     else:
         for nm, (di, do) in dict(
             wq=(d, cfg.n_heads * hd), wk=(d, cfg.n_kv_heads * hd),
@@ -136,18 +138,32 @@ def param_template(cfg) -> Dict[str, Any]:
             blk["bv"] = stacked((cfg.n_kv_heads * hd,), None, L)
 
     if cfg.is_moe:
-        e, ffe = cfg.n_experts, cfg.d_ff_expert
-        blk["router"] = stacked((d, e), d, L)
-        blk["w_gate"] = stacked((e, d, ffe), d, L)
-        blk["w_up"] = stacked((e, d, ffe), d, L)
-        blk["w_down"] = stacked((e, ffe, d), ffe, L)
+        # leading dense layers keep their FFN in t["dense"] and the MoE
+        # layers theirs in t["moe"], each stacked over its own layers; the
+        # attention half stays in t["layers"], stacked over all of them
+        kd = cfg.first_k_dense
+        moe_blk = {} if kd else blk
+        lm = L - kd
+        e, eh, ffe = cfg.n_experts, cfg.held_experts, cfg.d_ff_expert
+        moe_blk["router"] = stacked((d, e), d, lm)
+        moe_blk["w_gate"] = stacked((eh, d, ffe), d, lm)
+        moe_blk["w_up"] = stacked((eh, d, ffe), d, lm)
+        moe_blk["w_down"] = stacked((eh, ffe, d), ffe, lm)
         if cfg.n_shared_experts:
             sff = cfg.n_shared_experts * ffe
             for nm, (di, do) in dict(
                 shared_w_gate=(d, sff), shared_w_up=(d, sff), shared_w_down=(sff, d)
             ).items():
                 shape, fan, dip = _lin(cfg, di, do)
-                blk[nm] = stacked(shape, fan, L, dip)
+                moe_blk[nm] = stacked(shape, fan, lm, dip)
+        if kd:
+            t["moe"] = moe_blk
+            t["dense"] = {}
+            for nm, (di, do) in dict(
+                w_gate=(d, cfg.d_ff), w_up=(d, cfg.d_ff), w_down=(cfg.d_ff, d)
+            ).items():
+                shape, fan, dip = _lin(cfg, di, do)
+                t["dense"][nm] = stacked(shape, fan, kd, dip)
     else:
         for nm, (di, do) in dict(
             w_gate=(d, cfg.d_ff), w_up=(d, cfg.d_ff), w_down=(cfg.d_ff, d)
@@ -266,8 +282,67 @@ def _fuses_rmsnorm(cfg) -> bool:
     return "rmsnorm" in api.get_backend(cfg.matmul_backend).prologues
 
 
-def _transformer_block(x, lp, cfg, *, positions, rope, cache, kv_chunk,
-                       constrain, plan=None, unroll=False, attn_backend=None):
+def _rope(cfg, positions):
+    """The per-forward rope tables of the attention layers."""
+    dim = cfg.qk_rope_head_dim if cfg.use_mla else cfg.resolved_head_dim
+    return layers.rope_tables(positions, dim, cfg.rope_theta, cfg.rope_yarn)
+
+
+def _ffn_half(x, lp, i, cfg, ffn_params, *, fuse_norm, constrain, plan=None,
+              dropless=False):
+    """The FFN half of layer ``i``, skip connection included: (x, aux,
+    moe stats (routed, rows, dropped)).
+
+    Dense configs run ``lp``'s SwiGLU; MoE configs ``moe.routed_ffn``
+    (``dropless`` at inference).  With ``cfg.first_k_dense`` leading dense
+    layers the FFN weights are not in ``lp`` but stacked apart
+    (``ffn_params``: the params' "dense" and "moe" trees), and the layer
+    index picks the half under ``lax.cond``: attention and the cache stay
+    one scan over all layers."""
+    zero = jnp.zeros((), jnp.float32)
+
+    def dense(x, wp):
+        ffn_in, ffn_g = (
+            (x, lp["ffn_norm"]) if fuse_norm
+            else (layers.rms_norm(x, lp["ffn_norm"], cfg.norm_eps), None)
+        )
+        # skip connection fused into the down-projection
+        return (moe.dense_ffn(ffn_in, wp, cfg, constrain=constrain, residual=x,
+                              norm=ffn_g), zero, jnp.zeros((3,), jnp.int32))
+
+    def routed(x, wp):
+        # the router and the experts both read the normed stream; MoE keeps
+        # the explicit norm (fusing it into each expert dispatch would
+        # recompute it per projection)
+        ffn_in = layers.rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
+        f, aux, stats = moe.routed_ffn(ffn_in, wp, cfg, plan=plan,
+                                       constrain=constrain, dropless=dropless)
+        return x + f, aux, stats
+
+    if not cfg.is_moe:
+        return dense(x, lp)
+    kd = cfg.first_k_dense
+    if not kd:
+        return routed(x, lp)
+
+    def layer_of(tree, j):
+        return jax.tree_util.tree_map(
+            lambda t: jax.lax.dynamic_index_in_dim(t, j, keepdims=False), tree)
+
+    return jax.lax.cond(
+        i < kd,
+        lambda x: dense(x, layer_of(ffn_params["dense"], jnp.minimum(i, kd - 1))),
+        lambda x: routed(x, layer_of(ffn_params["moe"], jnp.maximum(i - kd, 0))),
+        x)
+
+
+def _ffn_params(params):
+    return {k: params[k] for k in ("dense", "moe") if k in params}
+
+
+def _transformer_block(x, lp, i, cfg, ffn_params, *, positions, rope, cache,
+                       kv_chunk, constrain, plan=None, unroll=False,
+                       attn_backend=None):
     fuse_norm = _fuses_rmsnorm(cfg)
     attn_in, attn_g = (
         (x, lp["attn_norm"]) if fuse_norm
@@ -283,26 +358,13 @@ def _transformer_block(x, lp, cfg, *, positions, rope, cache, kv_chunk,
         kv_chunk=kv_chunk, constrain=constrain, unroll=unroll,
         rope=rope, residual=x, norm=attn_g, attn_backend=attn_backend,
     )
-    if cfg.is_moe:
-        # the router and the experts both read the normed stream; MoE keeps
-        # the explicit norm (fusing it into each expert dispatch would
-        # recompute it per projection)
-        ffn_in = layers.rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
-        f, aux, _ = moe.moe_ffn(ffn_in, lp, cfg, plan=plan,
-                                constrain=constrain)
-        x = x + f
-    else:
-        ffn_in, ffn_g = (
-            (x, lp["ffn_norm"]) if fuse_norm
-            else (layers.rms_norm(x, lp["ffn_norm"], cfg.norm_eps), None)
-        )
-        # skip connection fused into the down-projection
-        x = moe.dense_ffn(ffn_in, lp, cfg, constrain=constrain, residual=x,
-                          norm=ffn_g)
-        aux = jnp.zeros((), jnp.float32)
+    # a cache means inference, where the experts drop nothing
+    x, aux, stats = _ffn_half(x, lp, i, cfg, ffn_params, fuse_norm=fuse_norm,
+                              constrain=constrain, plan=plan,
+                              dropless=cache is not None)
     # the scan carry is saved per layer for backward — constraining it keeps
     # the saved residuals in the sequence-sharded layout (16x less memory)
-    return constrain(x, "act_btd"), new_cache, aux
+    return constrain(x, "act_btd"), new_cache, aux, stats
 
 
 def _mamba_block(x, lp, cfg, *, cache, constrain):
@@ -312,7 +374,13 @@ def _mamba_block(x, lp, cfg, *, cache, constrain):
                          residual=x)
 
 
-def forward(
+def forward(*args, **kwargs) -> Tuple[jax.Array, Optional[Dict], jax.Array]:
+    """Returns (logits, new_cache, aux_loss); see :func:`forward_counted`,
+    which also returns the MoE layers' counts."""
+    return forward_counted(*args, **kwargs)[:3]
+
+
+def forward_counted(
     params: Dict[str, Any],
     cfg,
     *,
@@ -336,8 +404,10 @@ def forward(
                                                # attention core ("flash" routes
                                                # serving prefill through the
                                                # fused kernel; forward-only)
-) -> Tuple[jax.Array, Optional[Dict], jax.Array]:
-    """Returns (logits, new_cache, aux_loss).
+) -> Tuple[jax.Array, Optional[Dict], jax.Array, jax.Array]:
+    """Returns (logits, new_cache, aux_loss, moe_stats): moe_stats sums the
+    MoE layers' (routed, rows, dropped) counts (``moe.routed_ffn``; zeros
+    without MoE).
 
     Distribution enters through ``plan``: its activation constraints replace
     the old bare ``constrain`` callback, and DiP weights that carry the
@@ -357,38 +427,38 @@ def forward(
     positions = start + jnp.arange(s, dtype=jnp.int32)
 
     remat = cfg.remat == "block"
+    stats_total = jnp.zeros((3,), jnp.int32)
 
     if cfg.ssm_state:
         x, new_layer_caches = _scan_mamba(params, cfg, x, cache, remat, constrain,
                                           unroll, kv_chunk, attn_backend)
-        if cfg.is_hybrid:
-            pass  # handled inside _scan_mamba
         aux_total = jnp.zeros((), jnp.float32)
     else:
         # RoPE cos/sin hoisted out of the per-layer path: position-only, so
         # ONE table per forward (a scan constant) instead of n_layers
         # transcendental sweeps
-        rope_dim = cfg.qk_rope_head_dim if cfg.use_mla else cfg.resolved_head_dim
-        rope = layers.rope_tables(positions, rope_dim, cfg.rope_theta)
+        rope = _rope(cfg, positions)
+        ffn_params = _ffn_params(params)
 
         def block(carry, xs):
-            x, aux = carry
-            lp, lcache = xs
+            x, aux, stats = carry
+            lp, lcache, i = xs
             if lcache is not None:
                 lcache = dict(lcache, pos=start)  # all layers share the position
-            x, new_cache, aux_i = _transformer_block(
-                x, lp, cfg, positions=positions, rope=rope, cache=lcache,
-                kv_chunk=kv_chunk, constrain=constrain, plan=plan,
+            x, new_cache, aux_i, stats_i = _transformer_block(
+                x, lp, i, cfg, ffn_params, positions=positions, rope=rope,
+                cache=lcache, kv_chunk=kv_chunk, constrain=constrain, plan=plan,
                 unroll=unroll, attn_backend=attn_backend,
             )
             if new_cache is not None:
                 new_cache = _strip_pos(new_cache)
-            return (x, aux + aux_i), new_cache
+            return (x, aux + aux_i, stats + stats_i), new_cache
 
         block_fn = jax.checkpoint(block) if remat else block
         layer_caches = cache["layers"] if cache is not None else None
-        (x, aux_total), new_layer_caches = jax.lax.scan(
-            block_fn, (x, jnp.zeros((), jnp.float32)), (params["layers"], layer_caches),
+        (x, aux_total, stats_total), new_layer_caches = jax.lax.scan(
+            block_fn, (x, jnp.zeros((), jnp.float32), stats_total),
+            (params["layers"], layer_caches, jnp.arange(cfg.n_layers)),
             unroll=cfg.n_layers if unroll else 1,
         )
 
@@ -408,7 +478,7 @@ def forward(
         # fused lm_head+CE training path: the caller feeds these hidden
         # states straight into kernels.lm_head_ce, so the (B, S, V) logits
         # are never formed at all
-        return x, new_cache, aux_total
+        return x, new_cache, aux_total, stats_total
 
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     if cfg.tie_embeddings:
@@ -425,7 +495,7 @@ def forward(
         lane = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
         logits = jnp.where(lane < cfg.vocab_size, logits, -1e30)
     logits = constrain(logits, "logits")
-    return logits, new_cache, aux_total
+    return logits, new_cache, aux_total, stats_total
 
 
 def _scan_mamba(params, cfg, x, cache, remat, constrain, unroll=False,
@@ -601,9 +671,12 @@ def init_paged_cache(cfg, num_blocks: int, block_size: int, *, slots: int,
     return {"layers": layers_cache}
 
 
-def paged_decode_step_fn(cfg, *, plan=None, constrain: Optional[Constrain] = None):
+def paged_decode_step_fn(cfg, *, plan=None, constrain: Optional[Constrain] = None,
+                         moe_stats: bool = False):
     """Returns ``step(params, cache, tokens, positions, block_tables)``
-    -> ``(logits, cache)`` — the serving engine's one compiled decode step.
+    -> ``(logits, cache)`` — the serving engine's one compiled decode step;
+    with ``moe_stats`` also the MoE layers' summed (routed, rows, dropped)
+    counts.  The experts drop nothing here (``moe.routed_ffn`` dropless).
 
     ``tokens``: (slots, 1) int32; ``positions``: (slots,) int32 absolute
     position of each slot's current token; ``block_tables``:
@@ -621,21 +694,22 @@ def paged_decode_step_fn(cfg, *, plan=None, constrain: Optional[Constrain] = Non
         cd = jnp.dtype(cfg.compute_dtype)
         x = params["embed"].astype(cd)[tokens]          # (B, 1, d)
         x = constrain(x, "act_btd")
+        stats = jnp.zeros((3,), jnp.int32)
 
         if cfg.ssm_state:
             x, new_layer_caches = _paged_scan_mamba(
                 params, cfg, x, cache, positions, block_tables, kvq, constrain
             )
         else:
-            rope_dim = cfg.qk_rope_head_dim if cfg.use_mla else cfg.resolved_head_dim
-            rope = layers.rope_tables(positions[:, None], rope_dim, cfg.rope_theta)
+            rope = _rope(cfg, positions[:, None])
             attn = (attention.paged_mla_attention if cfg.use_mla
                     else attention.paged_gqa_attention)
-
             fuse_norm = _fuses_rmsnorm(cfg)
+            ffn_params = _ffn_params(params)
 
-            def block(x, xs):
-                lp, lcache = xs
+            def block(carry, xs):
+                x, stats = carry
+                lp, lcache, i = xs
                 attn_in, attn_g = (
                     (x, lp["attn_norm"]) if fuse_norm
                     else (layers.rms_norm(x, lp["attn_norm"], cfg.norm_eps),
@@ -646,23 +720,14 @@ def paged_decode_step_fn(cfg, *, plan=None, constrain: Optional[Constrain] = Non
                     block_tables=block_tables, kv_quant=kvq,
                     constrain=constrain, rope=rope, residual=x, norm=attn_g,
                 )
-                if cfg.is_moe:
-                    ffn_in = layers.rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
-                    f, _, _ = moe.moe_ffn(ffn_in, lp, cfg, plan=plan,
-                                          constrain=constrain)
-                    x = x + f
-                else:
-                    ffn_in, ffn_g = (
-                        (x, lp["ffn_norm"]) if fuse_norm
-                        else (layers.rms_norm(x, lp["ffn_norm"], cfg.norm_eps),
-                              None)
-                    )
-                    x = moe.dense_ffn(ffn_in, lp, cfg, constrain=constrain,
-                                      residual=x, norm=ffn_g)
-                return constrain(x, "act_btd"), new_cache
+                x, _, stats_i = _ffn_half(
+                    x, lp, i, cfg, ffn_params, fuse_norm=fuse_norm,
+                    constrain=constrain, plan=plan, dropless=True)
+                return (constrain(x, "act_btd"), stats + stats_i), new_cache
 
-            x, new_layer_caches = jax.lax.scan(
-                block, x, (params["layers"], cache["layers"])
+            (x, stats), new_layer_caches = jax.lax.scan(
+                block, (x, stats),
+                (params["layers"], cache["layers"], jnp.arange(cfg.n_layers))
             )
 
         x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -679,6 +744,8 @@ def paged_decode_step_fn(cfg, *, plan=None, constrain: Optional[Constrain] = Non
             lane = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
             logits = jnp.where(lane < cfg.vocab_size, logits, -1e30)
         logits = constrain(logits, "logits")
+        if moe_stats:
+            return logits, {"layers": new_layer_caches}, stats
         return logits, {"layers": new_layer_caches}
 
     return step
@@ -878,18 +945,21 @@ def train_step_fn(cfg, optimizer, *, plan=None, constrain: Optional[Constrain] =
 
 
 def decode_step_fn(cfg, *, plan=None, constrain: Optional[Constrain] = None,
-                   unroll: bool = False, attn_backend: Optional[str] = None):
-    """Returns serve_step(params, cache, tokens) -> (logits, cache).
+                   unroll: bool = False, attn_backend: Optional[str] = None,
+                   moe_stats: bool = False):
+    """Returns serve_step(params, cache, tokens) -> (logits, cache), and
+    with ``moe_stats`` the MoE layers' summed (routed, rows, dropped)
+    counts as a third output.
 
     ``attn_backend="flash"`` routes the attention core through the fused
     ``api.attention`` kernel — the serving chunked-prefill path (forward
     only, so decode/prefill steps qualify; training does not)."""
 
     def step(params, cache, tokens):
-        logits, new_cache, _ = forward(
+        logits, new_cache, _, stats = forward_counted(
             params, cfg, tokens=tokens, cache=cache, plan=plan,
             constrain=constrain, unroll=unroll, attn_backend=attn_backend,
         )
-        return logits, new_cache
+        return (logits, new_cache, stats) if moe_stats else (logits, new_cache)
 
     return step

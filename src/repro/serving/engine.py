@@ -37,7 +37,9 @@ degraded to an ``xla``-compiled decode step, then failed — while its
 batch-mates keep streaming untouched.  Requests may carry deadlines
 (``ttl_s``); expired ones are swept each tick.  Counters
 (``faults_detected`` / ``retries`` / ``deadline_evictions`` /
-``degraded_requests``) surface in ``last_stats``.
+``degraded_requests``) surface in ``last_stats``, as do an MoE model's
+routing counts (``moe_routed`` / ``moe_rows`` / ``moe_dropped``), which its
+programs return and the engine reads at each fetch.
 """
 
 from __future__ import annotations
@@ -136,8 +138,11 @@ class Engine:
             plan=plan,
         )
 
-        self._decode = jax.jit(_named(
-            tf_model.paged_decode_step_fn(cfg, plan=plan), "engine_decode"))
+        # MoE programs also return their layers' (routed, rows, dropped)
+        # counts, kept on the device until the next fetch reads them
+        self._moe = cfg.is_moe
+        self._decode = jax.jit(_named(tf_model.paged_decode_step_fn(
+            cfg, plan=plan, moe_stats=self._moe), "engine_decode"))
         # chunked prefill routes through the fused flash-attention kernel
         # (api.attention backend "flash") whenever the logits stay local: the
         # kernel takes the chunk's cache offset as a *traced* q_offset, so
@@ -145,6 +150,7 @@ class Engine:
         # plans keep the GSPMD online-softmax path (the kernel is per-shard).
         self._prefill_fwd = jax.jit(_named(tf_model.decode_step_fn(
             cfg, plan=plan, attn_backend="flash" if plan is None else None,
+            moe_stats=self._moe,
         ), "engine_prefill_chunk"))
         self._import = jax.jit(kvc.make_import_fn(
             cfg, num_blocks, self.block_size, self.kv_quant
@@ -171,6 +177,10 @@ class Engine:
         self._rows_greedy = 0
         self._rows_drawn = 0
         self._filtered_draws = 0
+        self._moe_pending: List[Any] = []          # device counts not yet read
+        self._moe_routed = 0
+        self._moe_rows = 0
+        self._moe_dropped = 0
         self.last_stats: Dict[str, Any] = {}
         # reliability bookkeeping (docs/reliability.md §serving)
         self._tick = 0
@@ -322,6 +332,25 @@ class Engine:
                 uniforms=sampling.RowSeeds(seeds, counters),
             )
 
+    def _run(self, program, *args):
+        """Dispatch one model program; an MoE program's counts wait on the
+        device for ``_read_moe``."""
+        out = program(*args)
+        if self._moe:
+            self._moe_pending.append(out[2])
+        return out[0], out[1]
+
+    def _read_moe(self) -> None:
+        """Add the pending MoE counts to the host counters: called where the
+        host has just waited for the programs anyway, so the read is a copy
+        of finished results and no sync of its own."""
+        if self._moe_pending:
+            got = np.sum(jax.device_get(self._moe_pending), axis=0)
+            self._moe_pending = []
+            self._moe_routed += int(got[0])
+            self._moe_rows += int(got[1])
+            self._moe_dropped += int(got[2])
+
     # ------------------------------------------------------------- faults --
     def _handle_fault(self, req: ServeRequest) -> None:
         """A verified step tripped for ``req``: bounded retry (evict —
@@ -353,7 +382,8 @@ class Engine:
         if self._decode_xla is None:
             cfg_xla = dataclasses.replace(self.cfg, matmul_backend="xla")
             self._decode_xla = jax.jit(_named(
-                tf_model.paged_decode_step_fn(cfg_xla, plan=self.plan),
+                tf_model.paged_decode_step_fn(cfg_xla, plan=self.plan,
+                                              moe_stats=self._moe),
                 "engine_decode_xla"))
         return self._decode_xla
 
@@ -422,7 +452,8 @@ class Engine:
                 # instead of padding.
                 if plen - done >= c:
                     chunk = self._prefill_tokens[done:done + c][None]
-                    last_logits, self._prefill_cache = self._prefill_fwd(
+                    last_logits, self._prefill_cache = self._run(
+                        self._prefill_fwd,
                         self.params, self._prefill_cache, jnp.asarray(chunk)
                     )
                     done += c
@@ -430,7 +461,8 @@ class Engine:
                 else:
                     while done < plen:
                         tok = self._prefill_tokens[done:done + 1][None]
-                        last_logits, self._prefill_cache = self._prefill_fwd(
+                        last_logits, self._prefill_cache = self._run(
+                            self._prefill_fwd,
                             self.params, self._prefill_cache, jnp.asarray(tok)
                         )
                         done += 1
@@ -440,7 +472,8 @@ class Engine:
                 # cache rows >= plen, which the import drops and positions
                 # never reach
                 chunk = self._prefill_tokens[done:done + c][None]
-                last_logits, self._prefill_cache = self._prefill_fwd(
+                last_logits, self._prefill_cache = self._run(
+                    self._prefill_fwd,
                     self.params, self._prefill_cache, jnp.asarray(chunk)
                 )
                 done += c
@@ -465,6 +498,7 @@ class Engine:
         pos = (plen - 1) - (self._prefill_done - last_logits.shape[1])
         with telemetry.span("engine.fetch", rid=req.rid, n=1):
             row = _first_row(last_logits, np.int32(pos)).block_until_ready()
+            self._read_moe()
         if self.ecfg.verify and not _rows_finite(row)[0]:
             self._handle_fault(req)
             return
@@ -512,14 +546,15 @@ class Engine:
                 if any(r is not None and r.degraded for r in reqs)
                 else self._decode
             )
-            logits, self.kv.pools = decode(
-                self.params, self.kv.pools,
+            logits, self.kv.pools = self._run(
+                decode, self.params, self.kv.pools,
                 jnp.asarray(self._cur), jnp.asarray(self._ctx),
                 jnp.asarray(self.kv.block_tables),
             )
             self._decode_steps += 1
         with telemetry.span("engine.fetch", n=live):
             logits.block_until_ready()
+            self._read_moe()
         rows = logits[:, -1]
         next_tokens = self._sample_rows(rows, reqs)
         finite = np.asarray(_rows_finite(rows)) if self.ecfg.verify else None
@@ -560,6 +595,9 @@ class Engine:
             "sample_rows_greedy": self._rows_greedy,
             "sample_rows_drawn": self._rows_drawn,
             "sample_filtered_draws": self._filtered_draws,
+            "moe_routed": self._moe_routed,
+            "moe_rows": self._moe_rows,
+            "moe_dropped": self._moe_dropped,
             "wall_s": wall,
             "tok_per_s": (self._generated_total - gen0) / max(wall, 1e-9),
             "prefill_chunks": self._prefill_chunks,
