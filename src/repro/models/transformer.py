@@ -36,6 +36,8 @@ _id: Constrain = lambda x, tag: x
 
 __all__ = [
     "param_template",
+    "COMPUTE_DTYPE_ROLES",
+    "serving_params",
     "init_params",
     "param_specs",
     "quantize_params",
@@ -173,6 +175,52 @@ def param_template(cfg) -> Dict[str, Any]:
 
     t["layers"] = blk
     return t
+
+
+# The roles of ``param_template`` that the forward reads only through a cast
+# to the compute dtype: the projections (``layers.linear``), the embedding
+# (cast before its gather, and as the tied head), the MoE expert banks (cast
+# before ``ragged_dot`` / ``einsum``) and MLA's absorbed up-projections.
+# Every other role is read in float32 somewhere: norm gains (``rms_norm``,
+# the fused prologue), QKV biases (the f32 epilogue), the router (f32
+# scores) and the SSM leaves (``conv_w``, ``conv_b``, ``dt_bias``, ``A_log``,
+# ``D``).
+COMPUTE_DTYPE_ROLES = frozenset({
+    "embed", "lm_head",
+    "wq", "wk", "wv", "wo", "w_dkv", "w_krope", "w_uk", "w_uv",
+    "w_gate", "w_up", "w_down",
+    "shared_w_gate", "shared_w_up", "shared_w_down",
+    "in_proj", "out_proj",
+})
+
+
+def serving_params(cfg, params: Dict[str, Any]) -> Dict[str, Any]:
+    """``params`` with each leaf of a ``COMPUTE_DTYPE_ROLES`` role rounded
+    once to ``cfg.compute_dtype``: what the serving engine keeps.
+
+    The forward's per-call ``.astype`` then has nothing left to do, and
+    rounding once gives the values it gave, so the logits are bit-identical
+    while each step reads half the weight bytes (from float32).  Only a
+    narrowing is made: nothing is cast when the compute dtype is as wide as
+    the storage.  Quantized weights and every other role keep their storage.
+    The tree is rebuilt, never written to, so the caller's stays as it was
+    (a trainer keeps its float32 master).  A cast ``DipWeight`` keeps its
+    metadata and plan and drops its ABFT checksum, which described the old
+    storage; the serving programs verify none."""
+    cd = jnp.dtype(cfg.compute_dtype)
+
+    def cast(path, leaf):
+        if (getattr(path[-1], "key", None) not in COMPUTE_DTYPE_ROLES
+                or isinstance(leaf, api.QuantizedDipWeight)):
+            return leaf
+        dt = jnp.dtype(leaf.dtype)
+        if jnp.issubdtype(dt, jnp.floating) and dt.itemsize > cd.itemsize:
+            return leaf.astype(cd)
+        return leaf
+
+    return jax.tree_util.tree_map_with_path(
+        cast, params,
+        is_leaf=lambda x: isinstance(x, (api.DipWeight, api.QuantizedDipWeight)))
 
 
 def _map_template(t, fn):

@@ -20,6 +20,13 @@ rows are independent: a greedy request's output is bit-identical whether it
 runs alone or packed with arbitrary batch-mates — the property
 ``tests/test_serving.py`` pins down.
 
+At intake the engine keeps its weights as the programs read them: each leaf
+read only in the compute dtype is rounded to it once
+(``models.transformer.serving_params``), so a step reads half the bytes of
+float32 storage and serves bit-identical logits.  ``last_stats`` carries
+``intake_cast_bytes`` (storage rounded) and ``served_weight_bytes`` (what
+the engine holds), and the ``engine.intake`` span times it.
+
 Each tick leaves host spans in ``serving.telemetry`` (``engine.step`` and,
 under it, admit / prefill / import / decode / fetch / sample), so the time
 the host spends blocked on the device programs and on the token draw can be
@@ -66,6 +73,14 @@ __all__ = ["Engine", "EngineConfig"]
 # program per chunk shape, none per prompt length
 _first_row = jax.jit(lambda logits, i: jax.lax.dynamic_index_in_dim(logits[0], i))
 _rows_finite = jax.jit(lambda rows: jnp.isfinite(rows).all(-1))
+
+
+def _is_dip(x) -> bool:
+    return isinstance(x, api.DipWeight)
+
+
+def _storage_bytes(leaf) -> int:
+    return int((leaf.data if _is_dip(leaf) else leaf).nbytes)
 
 
 def _named(fn, name: str):
@@ -118,7 +133,23 @@ class Engine:
             params = plan.attach_params(params)
             shardings = plan.param_shardings(params)
             params = jax.tree_util.tree_map(jax.device_put, params, shardings)
-        self.params = params
+        # weight intake: each leaf the programs read only in the compute
+        # dtype is rounded to it once, here, before the KV pools take their
+        # memory; the engine keeps the rounded copy alone
+        with telemetry.span("engine.intake") as span:
+            self.params = tf_model.serving_params(cfg, params)
+            jax.block_until_ready(self.params)
+            cast = [_storage_bytes(a) for a, b in zip(
+                jax.tree_util.tree_leaves(params, is_leaf=_is_dip),
+                jax.tree_util.tree_leaves(self.params, is_leaf=_is_dip))
+                if a is not b]
+            span.n = len(cast)
+        del params
+        self._intake_stats = {
+            "intake_cast_bytes": sum(cast),
+            "served_weight_bytes": sum(
+                int(a.nbytes) for a in jax.tree_util.tree_leaves(self.params)),
+        }
 
         self.block_size = ecfg.block_size or cfg.kv_block_size
         self.kv_quant = ecfg.kv_quant if ecfg.kv_quant is not None else cfg.kv_quant
@@ -181,7 +212,7 @@ class Engine:
         self._moe_routed = 0
         self._moe_rows = 0
         self._moe_dropped = 0
-        self.last_stats: Dict[str, Any] = {}
+        self.last_stats: Dict[str, Any] = dict(self._intake_stats)
         # reliability bookkeeping (docs/reliability.md §serving)
         self._tick = 0
         self._faults_detected = 0
@@ -607,5 +638,6 @@ class Engine:
             "retries": self._retries_total,
             "deadline_evictions": self._deadline_evictions,
             "degraded_requests": self._degraded_requests,
+            **self._intake_stats,
         }
         return dict(self.results)
